@@ -19,6 +19,7 @@ import os
 import subprocess
 import sys
 import time
+import traceback
 
 sys.path.insert(0, "src")
 
@@ -50,13 +51,15 @@ QUICK = False
 BENCH_SCHEMA = "simfaas-bench-v1"
 
 
-def emit(name: str, us_per_call: float, derived: str, **extra):
+def emit(name: str, us_per_call, derived: str, **extra):
     """Record one bench row.  ``extra`` carries the structured fields of
     the ``--json`` schema: ``wall_clock_s`` (dict of label → seconds),
-    ``traces`` (dict of counter → count), ``bitdiff`` (float)."""
+    ``traces`` (dict of counter → count), ``bitdiff`` (float).
+    ``us_per_call=None`` records a row that was not measured."""
     ROWS.append({"name": name, "us_per_call": us_per_call,
                  "derived": derived, **extra})
-    print(f"{name},{us_per_call:.3f},{derived}", flush=True)
+    us = "not measured" if us_per_call is None else f"{us_per_call:.3f}"
+    print(f"{name},{us},{derived}", flush=True)
 
 
 def paper_cfg(sim_time=2e5, **kw):
@@ -509,11 +512,9 @@ def bench_scenario_grid():
     )
 
 
-def _sharded_child(quick: bool) -> None:
-    """Child-process body of ``bench_sharded_sweep`` (the parent forces
-    ``--xla_force_host_platform_device_count=4`` via XLA_FLAGS before jax
-    initialises): time the 3-axis grid single-device vs grid-sharded and
-    print one JSON payload line."""
+def _sharded_child(quick: bool) -> dict:
+    """Body of ``bench_sharded_sweep`` over the visible devices: time the
+    3-axis grid single-device vs grid-sharded; returns the row payload."""
     from repro.core import Execution
 
     if quick:
@@ -557,68 +558,80 @@ def _sharded_child(quick: bool) -> None:
     )
     cells = len(thresholds) * len(rates) * len(horizons)
     arrivals = cells * replicas * steps
-    print(
-        json.dumps(
-            {
-                "us_per_call": dt_shard / arrivals * 1e6,
-                "derived": (
-                    f"devices={D} cells={cells} traces={traces}(expect (0, 0) warm) "
-                    f"single={dt_single:.2f}s sharded={dt_shard:.2f}s "
-                    f"scaling={dt_single / dt_shard:.2f}x bitdiff={bitdiff:.1e}(=0)"
-                ),
-            }
-        )
+    return {
+        "us_per_call": dt_shard / arrivals * 1e6,
+        "derived": (
+            f"devices={D} cells={cells} traces={traces}(expect (0, 0) warm) "
+            f"single={dt_single:.2f}s sharded={dt_shard:.2f}s "
+            f"scaling={dt_single / dt_shard:.2f}x bitdiff={bitdiff:.1e}(=0)"
+        ),
+    }
+
+
+def _sharded_row(name: str, child_flag: str, body) -> None:
+    """Run a 4-device sharded bench and emit its row.
+
+    On the CPU the four devices are fake ones, and JAX fixes the device
+    count at first init, so ``body`` runs in a child process started with
+    ``--xla_force_host_platform_device_count=4``.  On an accelerator it
+    runs here over the real devices: a child could not take the chip this
+    process holds.  Fewer than 4 real devices: the row is not measured.
+    """
+    if jax.default_backend() != "cpu":
+        n = len(jax.devices())
+        if n < 4:
+            emit(name, None, f"not measured: {n} device(s) visible, 4 needed")
+            return
+        payload = body(QUICK)
+    else:
+        payload = _child_payload(child_flag)
+    emit(
+        name,
+        payload["us_per_call"],
+        payload["derived"],
+        **{k: payload[k] for k in ("wall_clock_s", "traces", "bitdiff")
+           if k in payload},
     )
 
 
-def bench_sharded_sweep():
-    """Grid-sharded sweep (Execution(shard='grid')) on 4 fake CPU devices.
-
-    JAX pins the device count at first init, so the measurement runs in a
-    child process with ``--xla_force_host_platform_device_count=4``;
-    derived reports the compile counts (expect zero warm traces), the
-    single-vs-sharded wall clock and the bitwise-equality check.  Fake
-    CPU devices share the same cores — the scaling number is about
-    dispatch overhead, not real parallel speedup.
-    """
+def _child_payload(child_flag: str) -> dict:
+    """Run this script with ``child_flag`` on 4 fake CPU devices and
+    return the JSON payload of its last output line."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
     ).strip()
-    args = [sys.executable, os.path.abspath(__file__), "--sharded-child"]
+    args = [sys.executable, os.path.abspath(__file__), child_flag]
     if QUICK:
         args.append("--quick")
-    try:
-        out = subprocess.run(
-            args, capture_output=True, text=True, env=env, timeout=1200
-        )
-    except subprocess.TimeoutExpired:
-        emit("bench_sharded_sweep", 0.0, "FAILED timeout=1200s")
-        return
+    out = subprocess.run(
+        args, capture_output=True, text=True, env=env, timeout=1200
+    )
     if out.returncode != 0:
-        emit("bench_sharded_sweep", 0.0, f"FAILED rc={out.returncode}")
-        print(out.stderr[-2000:], file=sys.stderr)
-        return
-    payload = None
-    for line in reversed(out.stdout.strip().splitlines()):
-        try:
-            payload = json.loads(line)
-            break
-        except json.JSONDecodeError:
-            continue
-    if not isinstance(payload, dict) or "us_per_call" not in payload:
-        emit("bench_sharded_sweep", 0.0, "FAILED no JSON payload in child stdout")
-        print(out.stdout[-2000:], file=sys.stderr)
-        return
-    emit("bench_sharded_sweep", payload["us_per_call"], payload["derived"])
+        raise RuntimeError(
+            f"{child_flag} child failed (rc={out.returncode}):\n"
+            f"{out.stderr[-2000:]}"
+        )
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def _block_sharded_child(quick: bool) -> None:
-    """Child-process body of ``bench_block_sharded``: a threshold × profile
-    grid with (irregular) metric windows on the f32 block backend,
-    single-device vs grid-sharded over the 4 fake devices, one JSON
-    payload line.  Backend: pallas on TPU, its jnp ref mirror elsewhere
-    (interpret-mode pallas timing would measure the interpreter)."""
+def bench_sharded_sweep():
+    """Grid-sharded sweep (Execution(shard='grid')) over 4 devices: fake
+    CPU devices in a child process, or the real ones (see
+    :func:`_sharded_row`).  Derived reports the compile counts (expect
+    zero warm traces), the single-vs-sharded wall clock and the bitwise
+    check.  Fake CPU devices share the same cores — there the scaling
+    number is about dispatch overhead, not real parallel speedup.
+    """
+    _sharded_row("bench_sharded_sweep", "--sharded-child", _sharded_child)
+
+
+def _block_sharded_child(quick: bool) -> dict:
+    """Body of ``bench_block_sharded``: a threshold × profile grid with
+    (irregular) metric windows on the f32 block backend, single-device vs
+    grid-sharded over the visible devices; returns the row payload.
+    Backend: pallas on TPU, its jnp ref mirror elsewhere (interpret-mode
+    pallas timing would measure the interpreter)."""
     from repro.core import Execution, scenario as scn
     from repro.core.scenario import TRACE_COUNTS as SCN_TRACE_COUNTS
 
@@ -676,71 +689,32 @@ def _block_sharded_child(quick: bool) -> None:
     )
     cells = n_thr * n_amp
     arrivals = int(single.windowed_arrivals.sum() * replicas)
-    print(
-        json.dumps(
-            {
-                "us_per_call": dt_shard / max(arrivals, 1) * 1e6,
-                "derived": (
-                    f"backend={backend} devices={D} cells={cells} "
-                    f"block_k={single.execution.block_k} "
-                    f"traces={tuple(traces.values())}(expect (0, 0) warm) "
-                    f"single={dt_single:.2f}s sharded={dt_shard:.2f}s "
-                    f"scaling={dt_single / dt_shard:.2f}x "
-                    f"bitdiff={bitdiff:.1e}(=0)"
-                ),
-                "wall_clock_s": {"single": dt_single, "sharded": dt_shard},
-                "traces": traces,
-                "bitdiff": bitdiff,
-            }
-        )
-    )
+    return {
+        "us_per_call": dt_shard / max(arrivals, 1) * 1e6,
+        "derived": (
+            f"backend={backend} devices={D} cells={cells} "
+            f"block_k={single.execution.block_k} "
+            f"traces={tuple(traces.values())}(expect (0, 0) warm) "
+            f"single={dt_single:.2f}s sharded={dt_shard:.2f}s "
+            f"scaling={dt_single / dt_shard:.2f}x "
+            f"bitdiff={bitdiff:.1e}(=0)"
+        ),
+        "wall_clock_s": {"single": dt_single, "sharded": dt_shard},
+        "traces": traces,
+        "bitdiff": bitdiff,
+    }
 
 
 def bench_block_sharded():
     """Grid-sharded f32 block sweep (the headline of the block-backend
     promotion): a threshold × profile grid with irregular metric windows
-    under ``Execution(backend=<block>, shard='grid')`` on 4 fake CPU
-    devices vs single-device — expect zero warm traces and bitdiff=0.
-    Fake CPU devices share cores, so scaling measures dispatch overhead
-    off-TPU; on real devices the row-parallel launch scales near-linearly.
+    under ``Execution(backend=<block>, shard='grid')`` over 4 devices (see
+    :func:`_sharded_row`) vs single-device — expect zero warm traces and
+    bitdiff=0.  Fake CPU devices share cores, so off-TPU scaling measures
+    dispatch overhead.
     """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
-    ).strip()
-    args = [sys.executable, os.path.abspath(__file__), "--block-sharded-child"]
-    if QUICK:
-        args.append("--quick")
-    try:
-        out = subprocess.run(
-            args, capture_output=True, text=True, env=env, timeout=1200
-        )
-    except subprocess.TimeoutExpired:
-        emit("bench_block_sharded", 0.0, "FAILED timeout=1200s")
-        return
-    if out.returncode != 0:
-        emit("bench_block_sharded", 0.0, f"FAILED rc={out.returncode}")
-        print(out.stderr[-2000:], file=sys.stderr)
-        return
-    payload = None
-    for line in reversed(out.stdout.strip().splitlines()):
-        try:
-            payload = json.loads(line)
-            break
-        except json.JSONDecodeError:
-            continue
-    if not isinstance(payload, dict) or "us_per_call" not in payload:
-        emit("bench_block_sharded", 0.0, "FAILED no JSON payload in child stdout")
-        print(out.stdout[-2000:], file=sys.stderr)
-        return
-    emit(
-        "bench_block_sharded",
-        payload["us_per_call"],
-        payload["derived"],
-        wall_clock_s=payload.get("wall_clock_s"),
-        traces=payload.get("traces"),
-        bitdiff=payload.get("bitdiff"),
-    )
+    _sharded_row("bench_block_sharded", "--block-sharded-child",
+                 _block_sharded_child)
 
 
 def bench_retry_sweep():
@@ -1220,49 +1194,52 @@ def main(argv=None) -> None:
     args = p.parse_args(argv)
     QUICK = args.quick
     if args.sharded_child:
-        _sharded_child(QUICK)
-        return
+        print(json.dumps(_sharded_child(QUICK)))
+        return 0
     if args.block_sharded_child:
-        _block_sharded_child(QUICK)
-        return
+        print(json.dumps(_block_sharded_child(QUICK)))
+        return 0
 
+    from repro.core.execution import enable_compile_cache
+
+    enable_compile_cache()
+    benches = [
+        bench_table1,
+        bench_fig5_sweep,
+        bench_scenario_grid,
+        bench_sharded_sweep,
+        bench_block_sharded,
+        bench_pallas_block,
+        bench_nhpp_sweep,
+        bench_retry_sweep,
+        bench_fused_rng,
+        bench_fleet_sweep,
+        bench_fault_sweep,
+        bench_online_service,
+    ]
+    if not QUICK:
+        benches[1:1] = [
+            bench_fig3_instance_distribution,
+            bench_fig4_ci_convergence,
+            bench_fig5_whatif_thresholds,
+        ]
+        benches += [
+            bench_fig1_concurrency_value,
+            bench_routing_policy,
+            bench_fig6_cold_start_probability,
+            bench_fig7_instance_count,
+            bench_fig8_wasted_capacity,
+            bench_sim_throughput,
+            bench_kernel_event_step,
+        ]
     print("name,us_per_call,derived")
-    if QUICK:
-        bench_table1()
-        bench_fig5_sweep()
-        bench_scenario_grid()
-        bench_sharded_sweep()
-        bench_block_sharded()
-        bench_pallas_block()
-        bench_nhpp_sweep()
-        bench_retry_sweep()
-        bench_fused_rng()
-        bench_fleet_sweep()
-        bench_fault_sweep()
-        bench_online_service()
-    else:
-        bench_table1()
-        bench_fig3_instance_distribution()
-        bench_fig4_ci_convergence()
-        bench_fig5_whatif_thresholds()
-        bench_fig5_sweep()
-        bench_scenario_grid()
-        bench_sharded_sweep()
-        bench_block_sharded()
-        bench_pallas_block()
-        bench_nhpp_sweep()
-        bench_retry_sweep()
-        bench_fused_rng()
-        bench_fleet_sweep()
-        bench_fault_sweep()
-        bench_online_service()
-        bench_fig1_concurrency_value()
-        bench_routing_policy()
-        bench_fig6_cold_start_probability()
-        bench_fig7_instance_count()
-        bench_fig8_wasted_capacity()
-        bench_sim_throughput()
-        bench_kernel_event_step()
+    failed = []
+    for bench in benches:
+        try:
+            bench()
+        except Exception:  # noqa: BLE001 - report every bench, then fail
+            traceback.print_exc()
+            failed.append(bench.__name__)
 
     if args.json:
         payload = {"schema": BENCH_SCHEMA, "quick": QUICK, "benchmarks": ROWS}
@@ -1270,6 +1247,10 @@ def main(argv=None) -> None:
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=2)
         print(f"wrote {args.json}", file=sys.stderr)
+    if failed:
+        print(f"FAILED benches: {', '.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 def _roofline_rows() -> dict:
@@ -1291,4 +1272,4 @@ def _roofline_rows() -> dict:
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

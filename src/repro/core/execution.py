@@ -34,6 +34,8 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+import os
+import pathlib
 from typing import Any, Callable, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -447,22 +449,54 @@ class Execution:
         length); ``block_k=None`` derives it from ``n_steps`` and the
         :data:`BLOCK_K_VMEM_BUDGET` for the three ``[block_r, block_k]``
         f32 sample blocks — ``min(K, budget)``, so short streams run as
-        one chunk and long ones chunk at the VMEM ceiling.  The launcher
+        one chunk and long ones chunk at the VMEM ceiling.  Either is
+        rounded up to whole 128-lane tiles (:func:`lane_chunk`).  The launcher
         pads ``K`` up to a ``block_k`` multiple either way (the
         ``K % block_k == 0`` rule), so every choice is semantics-free;
         engines report the chosen value on the result's resolved plan.
         """
-        n = max(int(n_steps), 1)
-        if self.block_k is not None:
-            return min(self.block_k, n)
-        return min(n, _AUTO_BLOCK_K_MAX)
+        return lane_chunk(
+            _AUTO_BLOCK_K_MAX if self.block_k is None else self.block_k, n_steps
+        )
 
+
+# TPU vreg lane width: block-kernel chunks are whole lane tiles
+LANE = 128
 
 # Auto block_k VMEM budget: bytes allowed for the three f32 sample blocks
 # of one replica-row block (BLOCK_R=8 rows).  1 MiB / (3 · 8 · 4 B) =
 # 10922 columns, rounded down to a 128-lane multiple.
 BLOCK_K_VMEM_BUDGET = 1 << 20
-_AUTO_BLOCK_K_MAX = (BLOCK_K_VMEM_BUDGET // (3 * 8 * 4)) // 128 * 128
+_AUTO_BLOCK_K_MAX = (BLOCK_K_VMEM_BUDGET // (3 * 8 * 4)) // LANE * LANE
+
+
+def lane_chunk(block_k: int, n_steps: int) -> int:
+    """``block_k`` clamped to an ``n_steps``-long stream and rounded up to
+    whole :data:`LANE` tiles — the Pallas kernels read each event's
+    samples from its aligned lane tile; the launchers' inert padding
+    absorbs the extra events."""
+    k = min(int(block_k), max(int(n_steps), 1))
+    return -(-k // LANE) * LANE
+
+
+_REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins when it is set (JAX reads it
+    itself, and no other directory is set here); otherwise the fixed
+    ``<repo>/.jax_cache`` — fixed because the path is part of what a
+    later process must find again.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def capability_markdown() -> str:

@@ -706,18 +706,21 @@ def fleet_sweep_executable(mesh=None):
     if mesh is None:
         return _fleet_simulate_sweep
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     spec = PartitionSpec("grid")
 
     def fn(cfg, params, times, fids, warms, colds):
         TRACE_COUNTS["fleet_sweep_sharded"] += 1
-        return shard_map(
+        # check_vma=False: rows are independent and the body has no
+        # collectives; the scan's constant initial carry would otherwise
+        # fail the varying-axes type check against its varying output
+        return jax.shard_map(
             functools.partial(_fleet_rows, cfg),
             mesh=mesh,
             in_specs=(spec,) * 5,
             out_specs=spec,
+            check_vma=False,
         )(params, times, fids, warms, colds)
 
     return jax.jit(fn, static_argnums=(0,))

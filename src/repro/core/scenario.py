@@ -1713,7 +1713,6 @@ def _block_sharded_executable(backend: str, mesh, kw_items: tuple):
     static launch config); traces pinned by
     ``TRACE_COUNTS["sweep_block_sharded"]``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     bspec = resolve_backend(backend)
@@ -1729,14 +1728,14 @@ def _block_sharded_executable(backend: str, mesh, kw_items: tuple):
 
     def fn(*arrays):
         TRACE_COUNTS["sweep_block_sharded"] += 1
-        # check_rep=False: the row-parallel body has no collectives, and
-        # pallas_call has no replication rule under shard_map
-        return shard_map(
+        # check_vma=False: the row-parallel body has no collectives, and
+        # pallas_call has no varying-axes rule under shard_map
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(spec,) * len(arrays),
             out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         )(*arrays)
 
     return jax.jit(fn)

@@ -980,15 +980,19 @@ def sweep_executable(mesh=None, donate: bool = True):
         TRACE_COUNTS[counter] += 1
         if mesh is None:
             return _sweep_rows(cfg, params, dts, warms, colds, *extras)
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         spec = PartitionSpec("grid")
-        return shard_map(
+        # check_vma=False: rows are independent and the body has no
+        # collectives; the scan's initial carry is built from constants
+        # (unvarying) while its output varies over "grid", which the
+        # varying-axes type check would otherwise reject
+        return jax.shard_map(
             functools.partial(_sweep_rows, cfg),
             mesh=mesh,
             in_specs=(spec,) * (4 + len(extras)),
             out_specs=spec,
+            check_vma=False,
         )(params, dts, warms, colds, *extras)
 
     return jax.jit(

@@ -20,7 +20,7 @@ the *throughput* engine for many-replica/many-cell what-if sweeps over
 horizons where f32 clocks are exact enough.  The f64 ``lax.scan`` simulator
 in ``repro.core`` remains the exactness path; ``kernels/ref.py`` mirrors
 these kernels in pure f32 jnp (same arithmetic order, same tie-breaks) so
-the two are bit-comparable and serve as the interpreter fallback off-TPU.
+the two are bit-comparable; the kernels run interpreted only on the CPU.
 
 Semantics per arrival (identical to ``core.simulator`` including the
 measurement window): integrate running/idle instance-time over the window
@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.execution import register_backend
+from repro.core.execution import LANE, lane_chunk, register_backend
 from repro.core import drawplan as dp
 
 NEG = -1e30
@@ -64,10 +64,6 @@ NEG = -1e30
 # replica-block row granularity of the sweep launcher (rows are padded to
 # a multiple of this before the kernel grid is formed)
 BLOCK_R = 8
-
-# lane width the par kernel pads its slot axis to so each of the c
-# ``finish`` planes is lane-aligned in VMEM (DESIGN.md §10)
-LANE = 128
 
 # Trace counter (kernel-local to avoid importing repro.core at call time):
 # incremented when faas_sweep_pallas / par_sweep_pallas is (re-)traced.
@@ -102,6 +98,49 @@ FLEET_ACC_COLS = 13
 # a power of two exactly representable in f32, larger than any padded
 # stream width, so the one-hot activation scatter never matches it
 NO_CHILD_F = float(1 << 30)
+
+
+def _iota(shape, dim):
+    """f32 index plane along ``dim`` (Mosaic builds integer iotas only)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(jnp.float32)
+
+
+def _event_col(ref, i):
+    """Column ``i`` of a ``[Rb, Kb]`` per-event block as an ``[Rb]`` vector.
+
+    A single dynamic lane does not lower on TPU, so this loads the aligned
+    128-lane tile holding event ``i`` and picks its lane with a masked max:
+    one vreg per event however wide the block, and exact for every value.
+    Blocks are whole lane tiles, or one partial tile (``Kb <= LANE``).
+    """
+    if ref.shape[1] <= LANE:
+        tile, lane = ref[...], i
+    else:
+        # lax.div/rem: i >= 0, and jnp's floor forms lower through sign
+        base = pl.multiple_of(jax.lax.div(i, LANE) * LANE, LANE)
+        tile, lane = ref[:, pl.ds(base, LANE)], jax.lax.rem(i, LANE)
+    ids = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    return jnp.max(jnp.where(ids == lane, tile, -jnp.inf), axis=1)
+
+
+def _interpret() -> bool:
+    """Pallas compiles on a TPU and runs interpreted on the CPU, the test
+    platform; any other platform is refused rather than interpreted."""
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the pallas backend runs on a TPU (or interpreted on the CPU "
+            f"for tests), not on platform {platform!r}; use backend='scan'"
+        )
+    return platform == "cpu"
+
+
+def _pallas_call_32(kernel, inputs, **kw):
+    """``pl.pallas_call`` with the kernel traced in 32-bit mode: the
+    package runs with x64 on, and Mosaic has no 64-bit vector types (loop
+    indices, sums of masks and scalar constants would otherwise widen)."""
+    with jax.enable_x64(False):
+        return pl.pallas_call(kernel, **kw)(*inputs)
 
 
 def _faas_kernel(
@@ -209,7 +248,7 @@ def _faas_kernel(
     w_lo = wb_ref[...][:, :-1] if n_windows else None  # [Rb, W]
     w_hi = wb_ref[...][:, 1:] if n_windows else None
     g_times = grid_ref[...] if n_grid else None  # [Rb, G]
-    slot_iota = jax.lax.broadcasted_iota(jnp.float32, alive.shape, 1)
+    slot_iota = _iota(alive.shape, 1)
     if fused:
         # per-row stream keys/params live in VMEM once per chunk; draws are
         # regenerated per event from the global counter — no [Rb, Kb] sample
@@ -229,7 +268,7 @@ def _faas_kernel(
         # GLOBAL across k chunks, so the revisited output block spans the
         # whole padded stream; one-hot gather/scatter keeps it vectorized
         act0 = act_out[...]
-        k_iota = jax.lax.broadcasted_iota(jnp.float32, act0.shape, 1)
+        k_iota = _iota(act0.shape, 1)
         k0 = pl.program_id(1) * n_steps
     if crashes:
         doom0 = doom_out[...]
@@ -250,11 +289,11 @@ def _faas_kernel(
             if reliability:
                 fail_i, _ = dp.event_uniforms(f_keys[:, 0], f_keys[:, 1], gk)
         else:
-            dt = dt_ref[:, i]
-            warm_s = warm_ref[:, i]
-            cold_s = cold_ref[:, i]
+            dt = _event_col(dt_ref, i)
+            warm_s = _event_col(warm_ref, i)
+            cold_s = _event_col(cold_ref, i)
             if reliability:
-                fail_i = fail_ref[:, i]
+                fail_i = _event_col(fail_ref, i)
         # prestamped: the sample slot carries the absolute arrival time
         # (non-stationary/trace streams); PAD_TIME entries are inert.
         t_new = dt if prestamped else t + dt
@@ -346,15 +385,15 @@ def _faas_kernel(
             # evict the newest idle instances above it (DESIGN.md §15);
             # cap_e's leading 0-edge makes the segment index a plain count
             seg = (cap_e <= t_new[:, None]).astype(jnp.float32).sum(axis=1) - 1.0
-            cap_col = jax.lax.broadcasted_iota(jnp.float32, cap_v.shape, 1)
+            cap_col = _iota(cap_v.shape, 1)
             cap_now = (cap_v * (cap_col == seg[:, None])).sum(axis=1)  # [Rb]
             idle_now = (alive > 0) & (busy <= t_new[:, None])
             over = alive.sum(axis=1) - cap_now
             cre_a = creation[:, :, None]
             cre_b = creation[:, None, :]
             shape3 = (creation.shape[0], creation.shape[1], creation.shape[1])
-            ia = jax.lax.broadcasted_iota(jnp.float32, shape3, 1)
-            ib = jax.lax.broadcasted_iota(jnp.float32, shape3, 2)
+            ia = _iota(shape3, 1)
+            ib = _iota(shape3, 2)
             newer = (cre_b > cre_a) | ((cre_b == cre_a) & (ib < ia))
             rank = (
                 (idle_now[:, None, :] & newer).astype(jnp.float32).sum(axis=2)
@@ -378,8 +417,10 @@ def _faas_kernel(
         first_best = jnp.min(jnp.where(is_best, slot_iota, 1e9), axis=1)
 
         free = alive <= 0
-        any_free = free.any(axis=1)
         first_free = jnp.min(jnp.where(free, slot_iota, 1e9), axis=1)
+        # not free.any(): Mosaic lowers bool reductions through a float
+        # proxy that widens to f64 when the caller runs with x64 on
+        any_free = first_free < 1e9
         n_alive = alive.sum(axis=1)
 
         active = t_new <= t_end
@@ -387,8 +428,8 @@ def _faas_kernel(
             # Non-first attempts stay inert until their parent's failure /
             # timeout / rejection switched them on (inactive events still
             # advance the clock, integrate and expire — no-op arrivals).
-            is_first = first_ref[:, i]
-            child = child_ref[:, i]
+            is_first = _event_col(first_ref, i)
+            child = _event_col(child_ref, i)
             gf = (k0 + i).astype(jnp.float32)  # global event position
             act_i = jnp.where(k_iota == gf, act, 0.0).sum(axis=1)
             active = active & ((is_first > 0) | (act_i > 0))
@@ -418,7 +459,7 @@ def _faas_kernel(
         if crashes:
             # Exp(crash_rate) lifetime stamped at cold start (memoryless ⇒
             # hazard-equivalent); warm hits keep the instance's old doom
-            crash_i = crashu_ref[:, i]
+            crash_i = _event_col(crashu_ref, i)
             life = -jnp.log(1.0 - crash_i) / crate
             doom = jnp.where(
                 sel & is_cold[:, None], (t_new + life)[:, None], doom
@@ -630,6 +671,7 @@ def faas_sweep_pallas(
     K = fused_k if fused else dts.shape[1]
     assert R % block_r == 0, (R, block_r)
     assert K % block_k == 0, (K, block_k)
+    assert block_k <= LANE or block_k % LANE == 0, block_k  # _event_col tiles
     t_end = jnp.broadcast_to(jnp.asarray(t_end, jnp.float32), (R,))
     skip = jnp.broadcast_to(jnp.asarray(skip, jnp.float32), (R,))
     grid = (R // block_r, K // block_k)
@@ -744,14 +786,15 @@ def faas_sweep_pallas(
         # the per-slot doom plane persists across k chunks like the pool
         out_specs.append(state_spec)
         out_shape.append(jax.ShapeDtypeStruct((R, M), jnp.float32))
-    out = pl.pallas_call(
+    out = _pallas_call_32(
         kernel,
+        inputs,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(*inputs)
+    )
     alive_n, creation_n, busy_n, t_n, acc = out[:5]
     return alive_n, creation_n, busy_n, t_n[:, 0], acc
 
@@ -772,7 +815,7 @@ def _pad_rows(x, pad_c, fill=None):
     precision="f32",
     kind="block",
     shardable=True,
-    description="VMEM-resident f32 Pallas block kernel (interpret off-TPU)",
+    description="VMEM-resident f32 Pallas block kernel (interpreted on CPU)",
     engines=("scan", "temporal"),
 )
 def _pallas_sweep_rows(
@@ -805,7 +848,7 @@ def _pallas_sweep_rows(
     if fused is not None:
         C = alive0.shape[0]
         n = int(fused["n_steps"])
-        block_k = min(block_k, max(n, 1))
+        block_k = lane_chunk(block_k, n)
         pad_c = (-C) % BLOCK_R
         Kp = n + ((-n) % block_k)
         row_pad = lambda x: _pad_rows(x, pad_c, fill=1.0)
@@ -843,7 +886,7 @@ def _pallas_sweep_rows(
             ),
             block_r=BLOCK_R,
             block_k=block_k,
-            interpret=jax.default_backend() != "tpu",
+            interpret=_interpret(),
             reliability=t_timeout is not None,
             fused_dists=tuple(fused["dists"]),
             fused_k=Kp,
@@ -854,7 +897,7 @@ def _pallas_sweep_rows(
         )
         return out[4][:C], out[3][:C]
     C, n = dts.shape
-    block_k = min(block_k, max(n, 1))
+    block_k = lane_chunk(block_k, n)
     pad_c = (-C) % BLOCK_R
     pad_k = (-n) % block_k
 
@@ -918,7 +961,7 @@ def _pallas_sweep_rows(
         ),
         block_r=BLOCK_R,
         block_k=block_k,
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
         reliability=reliability,
         retries=retries,
         **rely_kw,
@@ -1020,16 +1063,19 @@ def _par_kernel(
     skip = skip_ref[...][:, 0]
     Rb, Mp = alive.shape
     c = concurrency
-    slot_iota = jax.lax.broadcasted_iota(jnp.float32, (Rb, Mp), 1)
+    slot_iota = _iota((Rb, Mp), 1)
     real = slot_iota < slots  # padded slots excluded from the pool
-    sub_iota = jax.lax.broadcasted_iota(jnp.float32, (Rb, c), 1)
+    sub_iota = _iota((Rb, c), 1)
+    # the sub-slot index across the [Rb, c, Mp] planes (a [Rb, c] mask
+    # cannot be broadcast along lanes in Mosaic)
+    sub3_iota = _iota((Rb, c, Mp), 1)
 
     def step(i, carry):
         alive, creation, finish2, t, acc = carry
         finish = finish2.reshape(Rb, c, Mp)
-        dt = dt_ref[:, i]
-        warm_s = warm_ref[:, i]
-        cold_s = cold_ref[:, i]
+        dt = _event_col(dt_ref, i)
+        warm_s = _event_col(warm_ref, i)
+        cold_s = _event_col(cold_ref, i)
         t_new = dt if prestamped else t + dt
         busy = finish.max(axis=1)  # [Rb, Mp]
 
@@ -1049,7 +1095,8 @@ def _par_kernel(
         flight_t = jnp.clip(
             jnp.minimum(finish, hi[:, None, None]) - lo[:, None, None], 0.0, None
         )
-        flight_sum = (flight_t * alive[:, None, :]).sum(axis=(1, 2))
+        # lane sums, then the c planes (Mosaic reduces one trailing axis)
+        flight_sum = (flight_t * alive[:, None, :]).sum(axis=2).sum(axis=1)
 
         expired = (alive > 0) & (expire <= t_new[:, None])
         alive = jnp.where(expired, 0.0, alive)
@@ -1063,8 +1110,8 @@ def _par_kernel(
         first_best = jnp.min(jnp.where(is_best, slot_iota, 1e9), axis=1)
 
         free = (alive <= 0) & real
-        any_free = free.any(axis=1)
         first_free = jnp.min(jnp.where(free, slot_iota, 1e9), axis=1)
+        any_free = first_free < 1e9
         n_alive = alive.sum(axis=1)
 
         active = t_new <= t_end
@@ -1088,7 +1135,7 @@ def _par_kernel(
         # a cold start repurposes a (possibly stale) slot: wipe it first
         wipe = sel & is_cold[:, None]
         finish = jnp.where(wipe[:, None, :], NEG, finish)
-        set3 = sel[:, None, :] & (sub_iota == first_sub[:, None])[:, :, None]
+        set3 = sel[:, None, :] & (sub3_iota == first_sub[:, None, None])
         finish = jnp.where(set3, (t_new + service)[:, None, None], finish)
         creation = jnp.where(sel & is_cold[:, None], t_new[:, None], creation)
         alive = jnp.where(sel & is_cold[:, None], 1.0, alive)
@@ -1156,6 +1203,7 @@ def par_sweep_pallas(
     K = dts.shape[1]
     assert R % block_r == 0, (R, block_r)
     assert K % block_k == 0, (K, block_k)
+    assert block_k <= LANE or block_k % LANE == 0, block_k  # _event_col tiles
     Mp = -(-slots // LANE) * LANE
     c = concurrency
     t_end = jnp.broadcast_to(jnp.asarray(t_end, jnp.float32), (R,))
@@ -1180,8 +1228,20 @@ def par_sweep_pallas(
         n_steps=block_k,
         prestamped=prestamped,
     )
-    out = pl.pallas_call(
+    out = _pallas_call_32(
         kernel,
+        [
+            alive0,
+            creation0,
+            finish0,
+            t0[:, None],
+            t_exp[:, None],
+            t_end[:, None],
+            skip[:, None],
+            dts,
+            warms,
+            colds,
+        ],
         grid=grid,
         in_specs=[
             state_spec,
@@ -1204,17 +1264,6 @@ def par_sweep_pallas(
             jax.ShapeDtypeStruct((R, PAR_ACC_COLS), jnp.float32),
         ],
         interpret=interpret,
-    )(
-        alive0,
-        creation0,
-        finish0,
-        t0[:, None],
-        t_exp[:, None],
-        t_end[:, None],
-        skip[:, None],
-        dts,
-        warms,
-        colds,
     )
     return out[4]
 
@@ -1311,12 +1360,12 @@ def _fleet_kernel(
     cap_e = cape_ref[...] if cap_steps else None  # [Rb, cap_steps]
     cap_v = capv_ref[...] if cap_steps else None  # [Rb, cap_steps]
     doom0 = doom_out[...] if crashes else None
-    slot_iota = jax.lax.broadcasted_iota(jnp.float32, alive.shape, 1)
-    rid = jax.lax.broadcasted_iota(jnp.float32, alive.shape, 0)[:, 0]  # [Rb]
+    slot_iota = _iota(alive.shape, 1)
+    rid = _iota(alive.shape, 0)[:, 0]  # [Rb]
     # the peak column is a MAX accumulator: seed from the prior chunk
     peak0 = jnp.max(acc0[:, FLEET_ACC_COLS - 1])
     if Q:
-        q_iota = jax.lax.broadcasted_iota(jnp.float32, (alive.shape[0], Q), 1)
+        q_iota = _iota((alive.shape[0], Q), 1)
         qt0, qw0, qc0 = qt_out[...], qw_out[...], qc_out[...]
 
     def routing(alive, creation, busy, t_new):
@@ -1326,8 +1375,8 @@ def _fleet_kernel(
         is_best = idle & (creation >= best[:, None]) & any_idle[:, None]
         first_best = jnp.min(jnp.where(is_best, slot_iota, 1e9), axis=1)
         free = alive <= 0
-        any_free = free.any(axis=1)
         first_free = jnp.min(jnp.where(free, slot_iota, 1e9), axis=1)
+        any_free = first_free < 1e9
         n_alive = alive.sum(axis=1)
         return any_idle, first_best, any_free, first_free, n_alive
 
@@ -1339,10 +1388,10 @@ def _fleet_kernel(
         else:
             alive, creation, busy, t, acc, peak = carry
             doom = None
-        dt = dt_ref[:, i]
-        fid = fid_ref[:, i]
-        warm_s = warm_ref[:, i]
-        cold_s = cold_ref[:, i]
+        dt = _event_col(dt_ref, i)
+        fid = _event_col(fid_ref, i)
+        warm_s = _event_col(warm_ref, i)
+        cold_s = _event_col(cold_ref, i)
         act = fid == rid
         t_new = dt if prestamped else t + dt
 
@@ -1391,11 +1440,11 @@ def _fleet_kernel(
             # the f64 scan's flattened [F*M] pool (DESIGN.md §15).  The
             # static loop over block rows keeps every tensor rank <= 3.
             seg = (cap_e <= t_new[:, None]).astype(jnp.float32).sum(axis=1) - 1.0
-            cap_col = jax.lax.broadcasted_iota(jnp.float32, cap_v.shape, 1)
+            cap_col = _iota(cap_v.shape, 1)
             cap_now = (cap_v * (cap_col == seg[:, None])).sum(axis=1)  # [Rb]
             idle_now = (alive > 0) & (busy <= t_new[:, None])
             over = alive.sum() - cap_now  # [Rb] (all rows agree)
-            row2 = jax.lax.broadcasted_iota(jnp.float32, alive.shape, 0)
+            row2 = _iota(alive.shape, 0)
             flat = row2 * float(alive.shape[1]) + slot_iota  # [Rb, M]
             rank = jnp.zeros(alive.shape, jnp.float32)
             for br in range(alive.shape[0]):
@@ -1506,7 +1555,7 @@ def _fleet_kernel(
             # hazard-equivalent); warm hits keep the instance's old doom.
             # No reliability layer in the fleet: an interrupted attempt is
             # one whose instance dies before the service completes.
-            crash_i = crashu_ref[:, i]
+            crash_i = _event_col(crashu_ref, i)
             life = -jnp.log(1.0 - crash_i) / crate
             doom = jnp.where(
                 sel & is_cold[:, None], (t_new + life)[:, None], doom
@@ -1571,7 +1620,7 @@ def _fleet_kernel(
         alive, creation, busy, t, acc, peak = jax.lax.fori_loop(
             0, n_steps, step, (alive, creation, busy, t, acc0, peak0)
         )
-    col_iota = jax.lax.broadcasted_iota(jnp.float32, acc.shape, 1)
+    col_iota = _iota(acc.shape, 1)
     acc = jnp.where(col_iota == float(FLEET_ACC_COLS - 1), peak, acc)
     alive_out[...] = alive
     creation_out[...] = creation
@@ -1630,6 +1679,7 @@ def fleet_sweep_pallas(
     )
     assert R % block_r == 0, (R, block_r)
     assert K % block_k == 0, (K, block_k)
+    assert block_k <= LANE or block_k % LANE == 0, block_k  # _event_col tiles
     grid = (R // block_r, K // block_k)
     acc_cols = FLEET_ACC_COLS + (FAULT_COLS if crashes or cap_steps else 0)
 
@@ -1698,14 +1748,15 @@ def fleet_sweep_pallas(
     if crashes:
         out_specs.append(state_spec)
         out_shape.append(jax.ShapeDtypeStruct((R, M), jnp.float32))
-    out = pl.pallas_call(
+    out = _pallas_call_32(
         kernel,
+        inputs,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(*inputs)
+    )
     return out[4], (out[5] if Q else None)
 
 
@@ -1725,7 +1776,7 @@ def _pallas_fleet_rows(
     """
     C, n = dts.shape
     assert C % BLOCK_R == 0, (C, BLOCK_R)
-    block_k = min(block_k, max(n, 1))
+    block_k = lane_chunk(block_k, n)
     pad_k = (-n) % block_k
 
     def pad(x, col_fill):
@@ -1758,7 +1809,7 @@ def _pallas_fleet_rows(
         queue_depth=queue_depth,
         block_r=BLOCK_R,
         block_k=block_k,
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
         prestamped=prestamped,
         **fault_kw,
     )
@@ -1774,7 +1825,7 @@ def _pallas_par_rows(t_exp, t_end, skip, dts, warms, colds, *, block_k, **kw):
     """The par engine's ``pallas`` row launcher: replica-block row padding
     + arrival-chunk padding around :func:`par_sweep_pallas`."""
     C, n = dts.shape
-    block_k = min(block_k, max(n, 1))
+    block_k = lane_chunk(block_k, n)
     pad_c = (-C) % BLOCK_R
     pad_k = (-n) % block_k
 
@@ -1794,7 +1845,7 @@ def _pallas_par_rows(t_exp, t_end, skip, dts, warms, colds, *, block_k, **kw):
         skip=_pad_rows(skip, pad_c, fill=1.0),
         block_r=BLOCK_R,
         block_k=block_k,
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
         **kw,
     )
     return acc[:C]

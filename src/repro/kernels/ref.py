@@ -10,7 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.execution import register_backend
+from repro.core.execution import lane_chunk, register_backend
 from repro.core import drawplan as dp
 from repro.models.attention import (  # noqa: F401
     decode_attention_ref,
@@ -78,8 +78,8 @@ def faas_sweep_ref(
     fused_k: int = 0,  # static event count when fused (no dts to size from)
 ):
     """f32 jnp mirror of ``faas_sweep_pallas`` (same arithmetic order, same
-    tie-breaks) — bit-comparable on CPU, and the interpreter fallback for
-    the what-if sweep's throughput backend off-TPU.  ``prestamped`` /
+    tie-breaks) — bit-comparable on CPU, and the reference the what-if
+    sweep's throughput backend is checked against.  ``prestamped`` /
     ``n_windows`` / ``n_grid`` mirror the kernel's absolute-timestamp,
     traced-window-bounds (acc gains ``5*n_windows`` columns: counts plus
     ∫running/∫idle) and transient-curve (``3*n_grid`` columns) extensions;
@@ -465,7 +465,7 @@ def _ref_sweep_rows(
     if fused is not None:
         C = alive0.shape[0]
         n = int(fused["n_steps"])
-        block_k = min(block_k, max(n, 1))
+        block_k = lane_chunk(block_k, n)
         pad_c = (-C) % BLOCK_R
         Kp = n + ((-n) % block_k)
         row_pad = lambda x: _pad_rows(x, pad_c, fill=1.0)
@@ -512,7 +512,7 @@ def _ref_sweep_rows(
         )
         return out[4][:C], out[3][:C]
     C, n = dts.shape
-    block_k = min(block_k, max(n, 1))
+    block_k = lane_chunk(block_k, n)
     pad_c = (-C) % BLOCK_R
     pad_k = (-n) % block_k
 
@@ -1011,7 +1011,7 @@ def faas_par_sweep_ref(
         flight_t = jnp.clip(
             jnp.minimum(finish, hi[:, None, None]) - lo[:, None, None], 0.0, None
         )
-        flight_sum = (flight_t * alive[:, None, :]).sum(axis=(1, 2))
+        flight_sum = (flight_t * alive[:, None, :]).sum(axis=2).sum(axis=1)
         expired = (alive > 0) & (expire <= t_new[:, None])
         alive = jnp.where(expired, 0.0, alive)
         in_flight = (finish > t_new[:, None, None]).sum(axis=1)

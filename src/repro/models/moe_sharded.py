@@ -40,20 +40,13 @@ from repro.configs.base import ModelConfig
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    """Version-compat shard_map: ``jax.shard_map(check_vma=...)`` on new
-    jax, ``jax.experimental.shard_map.shard_map(check_rep=...)`` on old."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` without the varying-axes type check."""
+    return jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=False,
     )
 
 

