@@ -1243,7 +1243,6 @@ def main(argv=None) -> None:
 
     if args.json:
         payload = {"schema": BENCH_SCHEMA, "quick": QUICK, "benchmarks": ROWS}
-        payload["roofline"] = _roofline_rows()
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=2)
         print(f"wrote {args.json}", file=sys.stderr)
@@ -1251,24 +1250,6 @@ def main(argv=None) -> None:
         print(f"FAILED benches: {', '.join(failed)}", file=sys.stderr)
         return 1
     return 0
-
-
-def _roofline_rows() -> dict:
-    """Roofline terms for the uploaded artifact: run
-    ``benchmarks/roofline.py`` over any dry-run artifacts present so the
-    BENCH_ci.json upload carries the compute/memory/collective split
-    alongside the wall-clock rows.  Dry-run artifacts are optional — an
-    empty row list (with the searched paths) is still recorded."""
-    import glob
-
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    paths = sorted(glob.glob(os.path.join("benchmarks", "results", "*.json")))
-    try:
-        import roofline
-
-        return {"paths": paths, "rows": roofline.table(paths)}
-    except Exception as e:  # pragma: no cover - depends on artifact presence
-        return {"paths": paths, "error": f"{type(e).__name__}: {e}"}
 
 
 if __name__ == "__main__":

@@ -80,6 +80,12 @@ Array = jax.Array
 # what-if sweep costs exactly one trace.
 TRACE_COUNTS: collections.Counter = collections.Counter()
 
+# Host spans at the sweep's layer boundaries (``repro.sweep.stage`` /
+# ``guard`` / ``launch`` / ``fetch`` / ``assemble``), written into the
+# profiler's trace on the device trace's clock; with no trace running each
+# is one TraceMe enter/exit.  None adds a sync or sits in a per-cell loop.
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass(frozen=True)
 class StaticConfig:
@@ -1209,157 +1215,160 @@ def sweep(
         # the attempt table is absolute f64 times — the whole grid runs
         # prestamped regardless of the base arrival process
         prestamped = True
-    bufs = ()
-    fplan = krows = pvals_list = None
-    if fused_mode:
-        from repro.core import drawplan as dpmod
+    # ---- host staging: the draw stacks (or fused key rows) and the
+    # per-row columns; enqueued device programs ride inside the span
+    with _span("repro.sweep.stage"):
+        bufs = ()
+        fplan = krows = pvals_list = None
+        if fused_mode:
+            from repro.core import drawplan as dpmod
 
-        plans, pvals_list = [], []
-        for c in draw_cfgs:
-            fp, pv = dpmod.lower_scenario(c)  # rejects retries/unlowerable
-            plans.append(fp)
-            pvals_list.append(pv)
-        if len(set(plans)) > 1:
-            raise ValueError(
-                "fused draws compile one DrawPlan for the whole grid; "
-                "sweeping distribution families or rate profiles across "
-                "draw cells needs draws='staged'"
-            )
-        fplan = plans[0]
-        if fplan.arrival.kind == "nhpp" and bspec.kind == "block":
-            raise ValueError(
-                "fused NHPP thinning is scan-backend only (the block "
-                "kernels have no profile.rate(t) at trace time); use "
-                "backend='scan' or draws='staged'"
-            )
-        # fused streams are gap-based (NHPP thinning happens inline), so
-        # the prestamped flag the staged NHPP path would set stays off
-        prestamped = False
-        kparts = []
-        for c in draw_cfgs:
-            key, sub = jax.random.split(key)  # same chained walk as staged
-            kparts.append(dpmod.stream_row_keys(sub, R, fail=rel is not None))
-        streams = ("arrival", "warm", "cold") + (
-            ("fail",) if rel is not None else ()
-        )
-        # [D*R, 2] per stream — the whole grid's sample state
-        krows = {
-            s: jnp.concatenate([kp[s] for kp in kparts]) for s in streams
-        }
-    else:
-        parts = []
-        for c in draw_cfgs:
-            key, sub = jax.random.split(key)
-            c_sim = Scenario.of(c, sim_time=max_sim)
-            if rel is not None:
-                smp_c, ext_c = draw_reliability_stream(c_sim, sub, R, n_steps)
-                part = tuple(smp_c) + tuple(ext_c)
-            else:
-                part = tuple(draw_workload_samples(c_sim, sub, R, n_steps))
-            if crashes_on:
-                # fold_in-salted off the cell key, so the base streams are
-                # bitwise-unchanged by the fault layer; positional per
-                # event (i.i.d.), so it need not ride the attempt-table
-                # sort — a cold start at event k consumes crash_u[k].
-                part = part + (
-                    draw_crash_uniforms(sub, R, part[0].shape[1]),
+            plans, pvals_list = [], []
+            for c in draw_cfgs:
+                fp, pv = dpmod.lower_scenario(c)  # rejects retries/unlowerable
+                plans.append(fp)
+                pvals_list.append(pv)
+            if len(set(plans)) > 1:
+                raise ValueError(
+                    "fused draws compile one DrawPlan for the whole grid; "
+                    "sweeping distribution families or rate profiles across "
+                    "draw cells needs draws='staged'"
                 )
-            parts.append(part)
-        # [D*R, K] per buffer; with retries K = n_steps * (max_retries + 1)
-        bufs = tuple(
-            jnp.concatenate([p[j] for p in parts]) for j in range(len(parts[0]))
-        )
-
-    # ---- param cells share draws: tile rows to C = D*Wn*R
-    param_combos = list(
-        itertools.product(*[vals[n] for n in param_names])
-    ) or [()]
-    Wn = len(param_combos)
-    C = D * Wn * R
-
-    def _param_col(name, default):
-        if name in param_names:
-            i = param_names.index(name)
-            col = np.asarray([pc[i] for pc in param_combos], np.float64)
-        else:
-            col = np.full((Wn,), default, np.float64)
-        return np.tile(np.repeat(col, R), D)  # [C]
-
-    def _draw_col(values):
-        return np.repeat(np.asarray(values, np.float64), Wn * R)  # [C]
-
-    thr_rows = _draw_col([c.expiration_threshold for c in draw_cfgs])
-    sim_rows = _param_col("sim_time", base.sim_time)
-    skip_rows = _param_col("skip_time", base.skip_time)
-    rely_rows = None
-    if rel is not None:
-        rely_rows = dict(
-            t_timeout=_param_col("t_timeout", rel.failure.timeout_or_inf),
-            p_fail=_param_col("p_fail", rel.failure.p_fail),
-            backoff_base=_draw_col(
-                [c.reliability.retry.backoff_base for c in draw_cfgs]
-            ),
-            backoff_mult=_draw_col(
-                [c.reliability.retry.backoff_mult for c in draw_cfgs]
-            ),
-            backoff_jitter=_draw_col(
-                [c.reliability.retry.backoff_jitter for c in draw_cfgs]
-            ),
-        )
-    fault_rows = None
-    if faults_on:
-        fault_rows = dict(crashes=crashes_on, cap_steps=cap_n)
-        if crashes_on:
-            fault_rows["crash_rate"] = _param_col(
-                "crash_rate", flt.crash_rate
+            fplan = plans[0]
+            if fplan.arrival.kind == "nhpp" and bspec.kind == "block":
+                raise ValueError(
+                    "fused NHPP thinning is scan-backend only (the block "
+                    "kernels have no profile.rate(t) at trace time); use "
+                    "backend='scan' or draws='staged'"
+                )
+            # fused streams are gap-based (NHPP thinning happens inline), so
+            # the prestamped flag the staged NHPP path would set stays off
+            prestamped = False
+            kparts = []
+            for c in draw_cfgs:
+                key, sub = jax.random.split(key)  # same chained walk as staged
+                kparts.append(dpmod.stream_row_keys(sub, R, fail=rel is not None))
+            streams = ("arrival", "warm", "cold") + (
+                ("fail",) if rel is not None else ()
             )
-        if cap_n:
-            if "capacity" in param_names:
-                i = param_names.index("capacity")
-                profs = [pc[i] for pc in param_combos]
-            else:
-                profs = [flt.capacity] * Wn
-            # [Wn, E] -> [C, E] in the (draw, param, replica) row order
-            mat = lambda a: np.tile(
-                np.repeat(np.asarray(a, np.float64), R, axis=0), (D, 1)
-            )
-            fault_rows["cap_edges"] = mat([p.edges for p in profs])
-            fault_rows["cap_values"] = mat([p.values for p in profs])
-
-    def _expand(x):
-        if Wn == 1:
-            return x
-        k = x.shape[1]  # per-buffer width: retries widen K past n_steps
-        return jnp.repeat(x.reshape(D, 1, R, k), Wn, axis=1).reshape(C, k)
-
-    samples = tuple(_expand(x) for x in bufs)
-
-    fused_scan = fused_block = None
-    if fused_mode:
-        # [C, 2] per-stream key pairs / param pairs — the grid's whole
-        # sample state; the O(C·K) buffers never exist
-        krows_exp = {s: _expand(v) for s, v in krows.items()}
-        pvals = {
-            s: np.asarray([pv[s] for pv in pvals_list], np.float64)
-            for s in ("arrival", "warm", "cold")
-        }
-        if bspec.kind == "native":
-            prows_exp = {
-                s: jnp.asarray(np.repeat(v, Wn * R, axis=0))
-                for s, v in pvals.items()
+            # [D*R, 2] per stream — the whole grid's sample state
+            krows = {
+                s: jnp.concatenate([kp[s] for kp in kparts]) for s in streams
             }
-            fused_scan = (fplan, int(n_steps), krows_exp, prows_exp)
         else:
-            fused_block = dict(
-                dists=fplan.dists,
-                keys=tuple(krows_exp[s] for s in ("arrival", "warm", "cold")),
-                params=tuple(
-                    np.repeat(np.asarray(pvals[s], np.float32), Wn * R, axis=0)
-                    for s in ("arrival", "warm", "cold")
-                ),
-                fail_keys=krows_exp.get("fail"),
-                n_steps=int(n_steps),
+            parts = []
+            for c in draw_cfgs:
+                key, sub = jax.random.split(key)
+                c_sim = Scenario.of(c, sim_time=max_sim)
+                if rel is not None:
+                    smp_c, ext_c = draw_reliability_stream(c_sim, sub, R, n_steps)
+                    part = tuple(smp_c) + tuple(ext_c)
+                else:
+                    part = tuple(draw_workload_samples(c_sim, sub, R, n_steps))
+                if crashes_on:
+                    # fold_in-salted off the cell key, so the base streams are
+                    # bitwise-unchanged by the fault layer; positional per
+                    # event (i.i.d.), so it need not ride the attempt-table
+                    # sort — a cold start at event k consumes crash_u[k].
+                    part = part + (
+                        draw_crash_uniforms(sub, R, part[0].shape[1]),
+                    )
+                parts.append(part)
+            # [D*R, K] per buffer; with retries K = n_steps * (max_retries + 1)
+            bufs = tuple(
+                jnp.concatenate([p[j] for p in parts]) for j in range(len(parts[0]))
             )
+
+        # ---- param cells share draws: tile rows to C = D*Wn*R
+        param_combos = list(
+            itertools.product(*[vals[n] for n in param_names])
+        ) or [()]
+        Wn = len(param_combos)
+        C = D * Wn * R
+
+        def _param_col(name, default):
+            if name in param_names:
+                i = param_names.index(name)
+                col = np.asarray([pc[i] for pc in param_combos], np.float64)
+            else:
+                col = np.full((Wn,), default, np.float64)
+            return np.tile(np.repeat(col, R), D)  # [C]
+
+        def _draw_col(values):
+            return np.repeat(np.asarray(values, np.float64), Wn * R)  # [C]
+
+        thr_rows = _draw_col([c.expiration_threshold for c in draw_cfgs])
+        sim_rows = _param_col("sim_time", base.sim_time)
+        skip_rows = _param_col("skip_time", base.skip_time)
+        rely_rows = None
+        if rel is not None:
+            rely_rows = dict(
+                t_timeout=_param_col("t_timeout", rel.failure.timeout_or_inf),
+                p_fail=_param_col("p_fail", rel.failure.p_fail),
+                backoff_base=_draw_col(
+                    [c.reliability.retry.backoff_base for c in draw_cfgs]
+                ),
+                backoff_mult=_draw_col(
+                    [c.reliability.retry.backoff_mult for c in draw_cfgs]
+                ),
+                backoff_jitter=_draw_col(
+                    [c.reliability.retry.backoff_jitter for c in draw_cfgs]
+                ),
+            )
+        fault_rows = None
+        if faults_on:
+            fault_rows = dict(crashes=crashes_on, cap_steps=cap_n)
+            if crashes_on:
+                fault_rows["crash_rate"] = _param_col(
+                    "crash_rate", flt.crash_rate
+                )
+            if cap_n:
+                if "capacity" in param_names:
+                    i = param_names.index("capacity")
+                    profs = [pc[i] for pc in param_combos]
+                else:
+                    profs = [flt.capacity] * Wn
+                # [Wn, E] -> [C, E] in the (draw, param, replica) row order
+                mat = lambda a: np.tile(
+                    np.repeat(np.asarray(a, np.float64), R, axis=0), (D, 1)
+                )
+                fault_rows["cap_edges"] = mat([p.edges for p in profs])
+                fault_rows["cap_values"] = mat([p.values for p in profs])
+
+        def _expand(x):
+            if Wn == 1:
+                return x
+            k = x.shape[1]  # per-buffer width: retries widen K past n_steps
+            return jnp.repeat(x.reshape(D, 1, R, k), Wn, axis=1).reshape(C, k)
+
+        samples = tuple(_expand(x) for x in bufs)
+
+        fused_scan = fused_block = None
+        if fused_mode:
+            # [C, 2] per-stream key pairs / param pairs — the grid's whole
+            # sample state; the O(C·K) buffers never exist
+            krows_exp = {s: _expand(v) for s, v in krows.items()}
+            pvals = {
+                s: np.asarray([pv[s] for pv in pvals_list], np.float64)
+                for s in ("arrival", "warm", "cold")
+            }
+            if bspec.kind == "native":
+                prows_exp = {
+                    s: jnp.asarray(np.repeat(v, Wn * R, axis=0))
+                    for s, v in pvals.items()
+                }
+                fused_scan = (fplan, int(n_steps), krows_exp, prows_exp)
+            else:
+                fused_block = dict(
+                    dists=fplan.dists,
+                    keys=tuple(krows_exp[s] for s in ("arrival", "warm", "cold")),
+                    params=tuple(
+                        np.repeat(np.asarray(pvals[s], np.float32), Wn * R, axis=0)
+                        for s in ("arrival", "warm", "cold")
+                    ),
+                    fail_keys=krows_exp.get("fail"),
+                    n_steps=int(n_steps),
+                )
 
     # ---- static combos: one compile each (outermost Python loop).
     # Native (scan) launches are *dispatched* here and drained in
@@ -1419,7 +1428,10 @@ def sweep(
             cells, win = col()
             all_summaries.extend(cells)
             windowed.append(win)
+        with _span("repro.sweep.assemble"):
+            return _assemble(all_summaries, windowed)
 
+    def _assemble(all_summaries, windowed) -> GridResult:
         # ---- assemble the named-axis grid (internal order: static,
         # draw, param)
         internal_names = static_names + draw_names + param_names
@@ -1596,9 +1608,10 @@ def _scan_dispatch(
         from repro.core.simulator import _simulate_sweep_fused
 
         fplan, n_f, krows, prows = fused
-        acc, t_last = _simulate_sweep_fused(
-            scfg, fplan, n_f, params, krows, prows
-        )
+        with _span("repro.sweep.launch"):
+            acc, t_last = _simulate_sweep_fused(
+                scfg, fplan, n_f, params, krows, prows
+            )
     else:
         mesh = None
         if plan.shard == "grid":
@@ -1611,7 +1624,7 @@ def _scan_dispatch(
                 params = jax.tree.map(pad_rows, params)
                 samples = tuple(pad_rows(x) for x in samples)
         fn = sweep_executable(mesh=mesh, donate=plan.donate)
-        with warnings.catch_warnings():
+        with warnings.catch_warnings(), _span("repro.sweep.launch"):
             # buffer donation is a no-op on CPU; the warning is expected
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable"
@@ -1619,8 +1632,9 @@ def _scan_dispatch(
             acc, t_last = fn(scfg, params, *samples)
 
     def collect():
-        acc_h = jax.tree.map(lambda x: np.asarray(x)[:C], acc)
-        t_h = np.asarray(t_last)[:C]
+        with _span("repro.sweep.fetch"):
+            acc_h = jax.tree.map(lambda x: np.asarray(x)[:C], acc)
+            t_h = np.asarray(t_last)[:C]
         if not prestamped and (t_h < sim_rows).any():
             raise RuntimeError(
                 "pre-drawn arrivals ended before sim_time "
@@ -1630,72 +1644,73 @@ def _scan_dispatch(
             raise RuntimeError(
                 "instance-pool overflow during sweep; raise Scenario.slots"
             )
-        n_cells = C // R
-        cell = jax.tree.map(
-            lambda x: x.reshape((n_cells, R) + x.shape[1:]), acc_h
-        )
-        bounds = np.asarray(wb, np.float64) if wb else None
-        widths = np.diff(bounds) if wb else None
-        summaries = []
-        w_cold = np.zeros((n_cells, W)) if W else None
-        w_arr = np.zeros((n_cells, W)) if W else None
-        w_inst = np.zeros((n_cells, W)) if W else None
-        for c in range(n_cells):
-            row = c * R
-            windows = None
-            if W:
-                windows = WindowedMetrics(
-                    bounds=bounds,
-                    n_cold=cell["w_cold"][c],
-                    n_warm=cell["w_warm"][c],
-                    n_arrivals=cell["w_arrivals"][c],
-                    time_running=cell["w_run_t"][c],
-                    time_idle=cell["w_idle_t"][c],
-                    n_fail=cell["w_fail"][c] if scfg.reliability else None,
-                )
-                w_cold[c] = windows.cold_start_prob
-                w_arr[c] = windows.n_arrivals.mean(axis=0)
-                w_inst[c] = (
-                    windows.time_running + windows.time_idle
-                ).mean(axis=0) / widths
-            rely_kw = {}
-            if scfg.reliability:
-                rely_kw = dict(
-                    n_timeout=cell["n_timeout"][c],
-                    n_fail=cell["n_fail"][c],
-                    n_retry=cell["n_retry"][c],
-                    n_abandon=cell["n_abandon"][c],
-                )
-            if scfg.crashes or scfg.cap_steps:
-                rely_kw.update(
-                    n_crash=cell["n_crash"][c],
-                    n_evict=cell["n_evict"][c],
-                    n_interrupt=cell["n_interrupt"][c],
-                )
-            summaries.append(
-                SimulationSummary(
-                    n_cold=cell["n_cold"][c],
-                    n_warm=cell["n_warm"][c],
-                    n_reject=cell["n_reject"][c],
-                    time_running=cell["time_running"][c],
-                    time_idle=cell["time_idle"][c],
-                    sum_cold_resp=cell["sum_cold_resp"][c],
-                    sum_warm_resp=cell["sum_warm_resp"][c],
-                    lifespan_sum=cell["lifespan_sum"][c],
-                    lifespan_count=cell["lifespan_count"][c],
-                    measured_time=float(sim_rows[row] - skip_rows[row]),
-                    histogram=cell["hist"][c]
-                    if scfg.track_histogram
-                    else None,
-                    overflow=cell["overflow"][c],
-                    windows=windows,
-                    **rely_kw,
-                )
+        with _span("repro.sweep.assemble"):
+            n_cells = C // R
+            cell = jax.tree.map(
+                lambda x: x.reshape((n_cells, R) + x.shape[1:]), acc_h
             )
-        win = (
-            dict(cold=w_cold, arrivals=w_arr, instances=w_inst) if W else None
-        )
-        return summaries, win
+            bounds = np.asarray(wb, np.float64) if wb else None
+            widths = np.diff(bounds) if wb else None
+            summaries = []
+            w_cold = np.zeros((n_cells, W)) if W else None
+            w_arr = np.zeros((n_cells, W)) if W else None
+            w_inst = np.zeros((n_cells, W)) if W else None
+            for c in range(n_cells):
+                row = c * R
+                windows = None
+                if W:
+                    windows = WindowedMetrics(
+                        bounds=bounds,
+                        n_cold=cell["w_cold"][c],
+                        n_warm=cell["w_warm"][c],
+                        n_arrivals=cell["w_arrivals"][c],
+                        time_running=cell["w_run_t"][c],
+                        time_idle=cell["w_idle_t"][c],
+                        n_fail=cell["w_fail"][c] if scfg.reliability else None,
+                    )
+                    w_cold[c] = windows.cold_start_prob
+                    w_arr[c] = windows.n_arrivals.mean(axis=0)
+                    w_inst[c] = (
+                        windows.time_running + windows.time_idle
+                    ).mean(axis=0) / widths
+                rely_kw = {}
+                if scfg.reliability:
+                    rely_kw = dict(
+                        n_timeout=cell["n_timeout"][c],
+                        n_fail=cell["n_fail"][c],
+                        n_retry=cell["n_retry"][c],
+                        n_abandon=cell["n_abandon"][c],
+                    )
+                if scfg.crashes or scfg.cap_steps:
+                    rely_kw.update(
+                        n_crash=cell["n_crash"][c],
+                        n_evict=cell["n_evict"][c],
+                        n_interrupt=cell["n_interrupt"][c],
+                    )
+                summaries.append(
+                    SimulationSummary(
+                        n_cold=cell["n_cold"][c],
+                        n_warm=cell["n_warm"][c],
+                        n_reject=cell["n_reject"][c],
+                        time_running=cell["time_running"][c],
+                        time_idle=cell["time_idle"][c],
+                        sum_cold_resp=cell["sum_cold_resp"][c],
+                        sum_warm_resp=cell["sum_warm_resp"][c],
+                        lifespan_sum=cell["lifespan_sum"][c],
+                        lifespan_count=cell["lifespan_count"][c],
+                        measured_time=float(sim_rows[row] - skip_rows[row]),
+                        histogram=cell["hist"][c]
+                        if scfg.track_histogram
+                        else None,
+                        overflow=cell["overflow"][c],
+                        windows=windows,
+                        **rely_kw,
+                    )
+                )
+            win = (
+                dict(cold=w_cold, arrivals=w_arr, instances=w_inst) if W else None
+            )
+            return summaries, win
 
     return collect
 
@@ -1825,15 +1840,18 @@ def _block_launch(
         # host-side gap sum for the caller's coverage guard.
         if window_rows is not None:
             kw = dict(kw, window_bounds=window_rows)
-        acc, t_last = bspec.launch(
-            *args, fused=fused, block_k=block_k, **rely_kw, **kw
-        )
-        acc = np.asarray(acc, np.float64)
+        with _span("repro.sweep.launch"):
+            acc, t_last = bspec.launch(
+                *args, fused=fused, block_k=block_k, **rely_kw, **kw
+            )
+        with _span("repro.sweep.fetch"):
+            acc = np.asarray(acc, np.float64)
+            t_last = np.asarray(t_last, np.float64)
         if acc[:, 7].sum() > 0:
             raise RuntimeError(
                 "instance-pool overflow during sweep; raise Scenario.slots"
             )
-        return acc, np.asarray(t_last, np.float64)
+        return acc, t_last
     if plan is not None and plan.shard == "grid":
         if rely_kw:
             raise ValueError(
@@ -1864,12 +1882,18 @@ def _block_launch(
                 )
             ),
         )
-        acc = np.asarray(fn(*args), np.float64)[:C]
+        with _span("repro.sweep.launch"):
+            out = fn(*args)
+        with _span("repro.sweep.fetch"):
+            acc = np.asarray(out, np.float64)[:C]
     else:
         launch_kw = dict(kw, block_k=block_k, **rely_kw, **fault_kw)
         if window_rows is not None:
             launch_kw["window_bounds"] = window_rows
-        acc = np.asarray(bspec.launch(*args, **launch_kw), np.float64)
+        with _span("repro.sweep.launch"):
+            out = bspec.launch(*args, **launch_kw)
+        with _span("repro.sweep.fetch"):
+            acc = np.asarray(out, np.float64)
     if acc[:, 7].sum() > 0:
         raise RuntimeError(
             "instance-pool overflow during sweep; raise Scenario.slots"
@@ -1919,7 +1943,8 @@ def _block_cells(
             # silently truncated.  f64 sum of the f32 gaps — the padded
             # kernel clock cannot be used for this check.  (Fused rows are
             # guarded on the kernel's own final clock after the launch.)
-            covered = np.asarray(dts, np.float64).sum(axis=1)
+            with _span("repro.sweep.guard"):
+                covered = np.asarray(dts, np.float64).sum(axis=1)
             if (covered < sim_rows).any():
                 raise RuntimeError(
                     "pre-drawn arrivals ended before sim_time "
@@ -1959,74 +1984,75 @@ def _block_cells(
                 "fused arrival stream ended before sim_time "
                 f"(min final t {t_last.min():.1f}); pass a larger `steps`"
             )
-    n_cells = len(thr_rows) // R
-    fault_on = bool(fr)
-    cols = (
-        ACC_COLS
-        + WINDOW_COLS * W
-        + (RELY_COLS if rel is not None else 0)
-        + (FAULT_COLS if fault_on else 0)
-    )
-    cell = acc.reshape(n_cells, R, cols)
-    A = ACC_COLS
-    RB = ACC_COLS + WINDOW_COLS * W  # reliability cols, then fault cols
-    FB = RB + (RELY_COLS if rel is not None else 0)
-    zeros = lambda: np.zeros((R,))
-    summaries = []
-    w_cold = np.zeros((n_cells, W)) if W else None
-    w_arr = np.zeros((n_cells, W)) if W else None
-    w_inst = np.zeros((n_cells, W)) if W else None
-    for c in range(n_cells):
-        row = c * R
-        windows = None
-        if W:
-            cold_c = cell[c, :, A : A + W]
-            served_c = cell[c, :, A + W : A + 2 * W]
-            windows = WindowedMetrics(
-                bounds=bounds,
-                n_cold=cold_c,
-                n_warm=served_c - cold_c,
-                n_arrivals=cell[c, :, A + 2 * W : A + 3 * W],
-                time_running=cell[c, :, A + 3 * W : A + 4 * W],
-                time_idle=cell[c, :, A + 4 * W : A + 5 * W],
-            )
-            w_cold[c] = windows.cold_start_prob
-            w_arr[c] = windows.n_arrivals.mean(axis=0)
-            w_inst[c] = (
-                windows.time_running + windows.time_idle
-            ).mean(axis=0) / widths
-        rely_kw = {}
-        if rel is not None:
-            rely_kw = dict(
-                n_timeout=cell[c, :, RB + 0],
-                n_fail=cell[c, :, RB + 1],
-                n_retry=cell[c, :, RB + 2],
-                n_abandon=cell[c, :, RB + 3],
-            )
-        if fault_on:
-            rely_kw.update(
-                n_crash=cell[c, :, FB + 0],
-                n_evict=cell[c, :, FB + 1],
-                n_interrupt=cell[c, :, FB + 2],
-            )
-        summaries.append(
-            SimulationSummary(
-                n_cold=cell[c, :, 0],
-                n_warm=cell[c, :, 1],
-                n_reject=cell[c, :, 2],
-                time_running=cell[c, :, 3],
-                time_idle=cell[c, :, 4],
-                sum_cold_resp=cell[c, :, 5],
-                sum_warm_resp=cell[c, :, 6],
-                lifespan_sum=zeros(),
-                lifespan_count=zeros(),
-                measured_time=float(sim_rows[row] - skip_rows[row]),
-                overflow=cell[c, :, 7],
-                windows=windows,
-                **rely_kw,
-            )
+    with _span("repro.sweep.assemble"):
+        n_cells = len(thr_rows) // R
+        fault_on = bool(fr)
+        cols = (
+            ACC_COLS
+            + WINDOW_COLS * W
+            + (RELY_COLS if rel is not None else 0)
+            + (FAULT_COLS if fault_on else 0)
         )
-    win = (
-        dict(cold=w_cold, arrivals=w_arr, instances=w_inst) if W else None
-    )
-    return summaries, win
+        cell = acc.reshape(n_cells, R, cols)
+        A = ACC_COLS
+        RB = ACC_COLS + WINDOW_COLS * W  # reliability cols, then fault cols
+        FB = RB + (RELY_COLS if rel is not None else 0)
+        zeros = lambda: np.zeros((R,))
+        summaries = []
+        w_cold = np.zeros((n_cells, W)) if W else None
+        w_arr = np.zeros((n_cells, W)) if W else None
+        w_inst = np.zeros((n_cells, W)) if W else None
+        for c in range(n_cells):
+            row = c * R
+            windows = None
+            if W:
+                cold_c = cell[c, :, A : A + W]
+                served_c = cell[c, :, A + W : A + 2 * W]
+                windows = WindowedMetrics(
+                    bounds=bounds,
+                    n_cold=cold_c,
+                    n_warm=served_c - cold_c,
+                    n_arrivals=cell[c, :, A + 2 * W : A + 3 * W],
+                    time_running=cell[c, :, A + 3 * W : A + 4 * W],
+                    time_idle=cell[c, :, A + 4 * W : A + 5 * W],
+                )
+                w_cold[c] = windows.cold_start_prob
+                w_arr[c] = windows.n_arrivals.mean(axis=0)
+                w_inst[c] = (
+                    windows.time_running + windows.time_idle
+                ).mean(axis=0) / widths
+            rely_kw = {}
+            if rel is not None:
+                rely_kw = dict(
+                    n_timeout=cell[c, :, RB + 0],
+                    n_fail=cell[c, :, RB + 1],
+                    n_retry=cell[c, :, RB + 2],
+                    n_abandon=cell[c, :, RB + 3],
+                )
+            if fault_on:
+                rely_kw.update(
+                    n_crash=cell[c, :, FB + 0],
+                    n_evict=cell[c, :, FB + 1],
+                    n_interrupt=cell[c, :, FB + 2],
+                )
+            summaries.append(
+                SimulationSummary(
+                    n_cold=cell[c, :, 0],
+                    n_warm=cell[c, :, 1],
+                    n_reject=cell[c, :, 2],
+                    time_running=cell[c, :, 3],
+                    time_idle=cell[c, :, 4],
+                    sum_cold_resp=cell[c, :, 5],
+                    sum_warm_resp=cell[c, :, 6],
+                    lifespan_sum=zeros(),
+                    lifespan_count=zeros(),
+                    measured_time=float(sim_rows[row] - skip_rows[row]),
+                    overflow=cell[c, :, 7],
+                    windows=windows,
+                    **rely_kw,
+                )
+            )
+        win = (
+            dict(cold=w_cold, arrivals=w_arr, instances=w_inst) if W else None
+        )
+        return summaries, win

@@ -31,7 +31,9 @@ immediately; the service then drains tick *t−1*'s results while the
 device crunches *t*.  The deferred path dispatches the exact same
 executable as the synchronous one, so a tick's recommendation is
 bitwise-equal to an offline ``sweep()`` on the same fitted profile and
-key.
+key.  Each drain is a ``repro.online.drain`` profiler span carrying the
+drained tick's index as ``tick=``; the sweep's own ``repro.sweep.*``
+spans (staging, launch, fetch, assembly) nest inside the tick.
 """
 
 from __future__ import annotations
@@ -393,52 +395,55 @@ class OnlineWhatIfService:
 
     def _drain(self, item) -> Recommendation:
         out, (tick, t_now, profile, key, stall) = item
-        grid = out.result() if hasattr(out, "result") else out
-        reason = stall
-        ok = np.asarray(grid.ok)
-        if reason is None and not ok.all():
-            reason = (
-                f"sweep produced non-finite metrics in {int((~ok).sum())} "
-                f"of {ok.size} grid cell(s)"
-            )
-        if reason is not None:
-            last_good = next(
-                (r for r in reversed(self.history) if not r.degraded), None
-            )
-            if last_good is not None:
-                # hold: re-issue the last healthy advice untouched — the
-                # governor must not be fed a choice read off garbage
-                rec = dataclasses.replace(
-                    last_good,
-                    tick=tick,
-                    t_now=t_now,
-                    degraded=True,
-                    degraded_reason=reason,
+        # under overlap the drained tick is the previous one; its fetch and
+        # assembly nest inside as repro.sweep.* spans
+        with jax.profiler.TraceAnnotation("repro.online.drain", tick=tick):
+            grid = out.result() if hasattr(out, "result") else out
+            reason = stall
+            ok = np.asarray(grid.ok)
+            if reason is None and not ok.all():
+                reason = (
+                    f"sweep produced non-finite metrics in {int((~ok).sum())} "
+                    f"of {ok.size} grid cell(s)"
                 )
-                self.history.append(rec)
-                return rec
-            # nothing good to hold yet: emit this tick's advice, flagged
-        plan: PlanResult = select_threshold(grid, self.config.cold_slo)
-        applied = self.governor.update(plan.expiration_threshold)
-        rec = Recommendation(
-            tick=tick,
-            t_now=t_now,
-            threshold=plan.expiration_threshold,
-            applied_threshold=applied,
-            predicted_cold_prob=plan.predicted_cold_prob,
-            predicted_cost=plan.predicted_cost,
-            predicted_goodput=plan.predicted_goodput,
-            predicted_avg_replicas=plan.predicted_avg_replicas,
-            headroom=self._capacity - plan.predicted_avg_replicas,
-            rate_mean=profile.mean_rate(),
-            profile=profile,
-            key=key,
-            grid=grid,
-            degraded=reason is not None,
-            degraded_reason=reason,
-        )
-        self.history.append(rec)
-        return rec
+            if reason is not None:
+                last_good = next(
+                    (r for r in reversed(self.history) if not r.degraded), None
+                )
+                if last_good is not None:
+                    # hold: re-issue the last healthy advice untouched — the
+                    # governor must not be fed a choice read off garbage
+                    rec = dataclasses.replace(
+                        last_good,
+                        tick=tick,
+                        t_now=t_now,
+                        degraded=True,
+                        degraded_reason=reason,
+                    )
+                    self.history.append(rec)
+                    return rec
+                # nothing good to hold yet: emit this tick's advice, flagged
+            plan: PlanResult = select_threshold(grid, self.config.cold_slo)
+            applied = self.governor.update(plan.expiration_threshold)
+            rec = Recommendation(
+                tick=tick,
+                t_now=t_now,
+                threshold=plan.expiration_threshold,
+                applied_threshold=applied,
+                predicted_cold_prob=plan.predicted_cold_prob,
+                predicted_cost=plan.predicted_cost,
+                predicted_goodput=plan.predicted_goodput,
+                predicted_avg_replicas=plan.predicted_avg_replicas,
+                headroom=self._capacity - plan.predicted_avg_replicas,
+                rate_mean=profile.mean_rate(),
+                profile=profile,
+                key=key,
+                grid=grid,
+                degraded=reason is not None,
+                degraded_reason=reason,
+            )
+            self.history.append(rec)
+            return rec
 
     # ---- checkpoint / restore -------------------------------------------
 
