@@ -217,6 +217,60 @@ def _lower_process(p, stream: str) -> Tuple[DrawSpec, Tuple[float, float]]:
     return DrawSpec(kind=kind, profile=profile), (float(p0), float(p1))
 
 
+def lower_staged(cfgs) -> Optional[Tuple[Tuple[str, str, str], dict]]:
+    """Lower a sweep's draw cells for the batched staging program.
+
+    The staged path's counterpart of :func:`lower_scenario`: the same
+    family dispatch, but every parameter is a traced value, the NHPP
+    profile's steps included, so re-rated cells and re-fit profiles share
+    one compile (``simulator.stage_draw_cells``).  Returns ``(kinds,
+    params)``: the three streams' draw kinds, the same in every cell, and
+    each cell's parameters as f64 arrays with a leading cell axis —
+    ``arrival``/``warm``/``cold`` ``[D, 2]``, and for NHPP arrivals the
+    profile's ``edges`` ``[D, E]`` and ``rates`` ``[D, E + 1]`` (the
+    envelope is the arrival's first parameter; profiles with fewer steps
+    pad with edges at +inf, which no candidate time reaches).  Returns
+    ``None`` where a cell's processes do not all lower to a family with a
+    raw draw in ``processes.RAW_DRAWS`` (NHPP arrivals over a step
+    profile aside), or where the cells' families differ: such grids are
+    staged cell by cell.
+    """
+    from repro.core.processes import RAW_DRAWS
+
+    kinds, rows, steps = None, [], []
+    for scn in cfgs:
+        try:
+            plan, par = lower_scenario(scn)
+        except ValueError:
+            return None
+        k = plan.dists
+        if kinds is not None and k != kinds:
+            return None
+        kinds = k
+        if not (k[0] == "nhpp" or k[0] in RAW_DRAWS) or not (
+            k[1] in RAW_DRAWS and k[2] in RAW_DRAWS
+        ):
+            return None
+        if k[0] == "nhpp":
+            try:
+                steps.append(plan.arrival.profile.step_arrays())
+            except NotImplementedError:
+                return None
+        rows.append([par[s] for s in ("arrival", "warm", "cold")])
+    pars = np.asarray(rows, np.float64)  # [D, 3, 2]
+    params = {s: pars[:, i] for i, s in enumerate(("arrival", "warm", "cold"))}
+    if steps:
+        e_max = max(len(e) for e, _ in steps)
+        pad = lambda e: (0, e_max - len(e))
+        params["edges"] = np.stack(
+            [np.pad(e, pad(e), constant_values=np.inf) for e, _ in steps]
+        )
+        params["rates"] = np.stack(
+            [np.pad(r, pad(e), mode="edge") for e, r in steps]
+        )
+    return kinds, params
+
+
 # ---------------------------------------------------------------------------
 # Key derivation (the staged fold_in chain, exported as raw uint32 pairs)
 # ---------------------------------------------------------------------------

@@ -28,6 +28,30 @@ Array = jax.Array
 _EPS = 1e-9  # service/inter-arrival times are clamped strictly positive
 
 
+def _exp_draw(key, shape, rate, _=None):
+    return jax.random.exponential(key, shape) / rate
+
+
+def _gauss_draw(key, shape, mu, sigma):
+    return mu + sigma * jax.random.normal(key, shape)
+
+
+def _lognorm_draw(key, shape, mu, sigma):
+    return jnp.exp(mu + sigma * jax.random.normal(key, shape))
+
+
+# Raw draws by ``draw_spec`` kind, the distribution's two parameters passed
+# as values (Python floats, or traced arrays): the processes' own samplers
+# and the batched staging program (``simulator.stage_draw_cells``) both
+# call these, so both draw bitwise the same numbers from the same key.
+RAW_DRAWS = {"exp": _exp_draw, "gauss": _gauss_draw, "lognorm": _lognorm_draw}
+
+
+def finish_sample(raw) -> Array:
+    """A raw draw as the engines take it: f32, clamped strictly positive."""
+    return jnp.maximum(raw.astype(jnp.float32), _EPS)
+
+
 def absolute_times_from_gaps(gaps) -> Array:
     """f64 absolute timestamps from an inter-arrival gap stream.
 
@@ -46,8 +70,7 @@ class SimProcess:
 
     def sample(self, key: Array, shape: tuple[int, ...]) -> Array:
         """Draw ``shape`` i.i.d. samples (f32, strictly positive)."""
-        out = self._raw_sample(key, shape)
-        return jnp.maximum(out.astype(jnp.float32), _EPS)
+        return finish_sample(self._raw_sample(key, shape))
 
     def _raw_sample(self, key: Array, shape: tuple[int, ...]) -> Array:
         raise NotImplementedError
@@ -93,7 +116,7 @@ class ExpSimProcess(SimProcess):
     rate: float
 
     def _raw_sample(self, key, shape):
-        return jax.random.exponential(key, shape) / self.rate
+        return _exp_draw(key, shape, self.rate)
 
     def mean(self):
         return 1.0 / self.rate
@@ -139,7 +162,7 @@ class GaussianSimProcess(SimProcess):
     sigma: float
 
     def _raw_sample(self, key, shape):
-        return self.mu + self.sigma * jax.random.normal(key, shape)
+        return _gauss_draw(key, shape, self.mu, self.sigma)
 
     def mean(self):
         # Exact truncated-normal mean correction is negligible for mu >> sigma;
@@ -204,7 +227,7 @@ class LogNormalSimProcess(SimProcess):
     sigma: float
 
     def _raw_sample(self, key, shape):
-        return jnp.exp(self.mu + self.sigma * jax.random.normal(key, shape))
+        return _lognorm_draw(key, shape, self.mu, self.sigma)
 
     def mean(self):
         return float(np.exp(self.mu + 0.5 * self.sigma**2))
@@ -368,6 +391,18 @@ class RateProfile:
     def max_rate(self) -> float:
         raise NotImplementedError
 
+    def step_arrays(self) -> tuple[np.ndarray, np.ndarray]:
+        """``(edges, rates)`` f64 arrays of a piecewise-constant profile.
+
+        The batched staging program (``simulator.stage_draw_cells``) takes
+        a profile's steps as traced values through this hook, so a re-fit
+        profile of the same bin count reuses its compile; a profile with no
+        step form raises and is staged cell by cell.
+        """
+        raise NotImplementedError(
+            f"{type(self).__name__} is not piecewise constant"
+        )
+
     def with_rate(self, rate: float) -> "RateProfile":
         """Return a copy rescaled so the *time-averaged* rate is ``rate``.
 
@@ -380,6 +415,31 @@ class RateProfile:
         raise NotImplementedError(
             f"{type(self).__name__} does not support rate rescaling"
         )
+
+
+def piecewise_rate(edges, rates, t) -> Array:
+    """r(t) of a step profile: ``rates[k]`` on ``[edges[k-1], edges[k])``."""
+    edges = jnp.asarray(edges, dtype=jnp.float64)
+    idx = jnp.searchsorted(edges, jnp.asarray(t, jnp.float64), side="right")
+    return jnp.asarray(rates, dtype=jnp.float64)[idx]
+
+
+def thin_arrivals(key, shape, lam, rate):
+    """NHPP arrival times by thinning (Lewis & Shedler), vectorised.
+
+    Candidates from a homogeneous Poisson at the envelope ``lam``; each is
+    kept with probability ``rate(t) / lam``; the kept times sort to the
+    front and the rejected ones to ``PAD_TIME``.  Returns ``(times,
+    coverage)``, coverage being each row's last candidate time.  ``lam``
+    may be a traced value (the batched staging program's).
+    """
+    k_gap, k_acc = jax.random.split(key)
+    gaps = jax.random.exponential(k_gap, shape) / lam
+    cand = jnp.cumsum(gaps.astype(jnp.float64), axis=-1)
+    u = jax.random.uniform(k_acc, shape)
+    accept = u * lam <= rate(cand)
+    times = jnp.sort(jnp.where(accept, cand, PAD_TIME), axis=-1)
+    return times, cand[..., -1]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -405,13 +465,16 @@ class PiecewiseConstantRate(RateProfile):
             raise ValueError("rates must be positive")
 
     def rate(self, t):
-        edges = jnp.asarray(self.edges, dtype=jnp.float64)
-        rates = jnp.asarray(self.rates, dtype=jnp.float64)
-        idx = jnp.searchsorted(edges, jnp.asarray(t, jnp.float64), side="right")
-        return rates[idx]
+        return piecewise_rate(*self.step_arrays(), t)
 
     def max_rate(self):
         return float(max(self.rates))
+
+    def step_arrays(self):
+        return (
+            np.asarray(self.edges, dtype=np.float64),
+            np.asarray(self.rates, dtype=np.float64),
+        )
 
     def mean_rate(self) -> float:
         """Time-averaged rate over the covered span.
@@ -597,15 +660,9 @@ class NHPPArrivalProcess(SimProcess, ArrivalTimeProcess):
         return "nhpp", (self.profile.max_rate(),)
 
     def arrival_times(self, key, shape):
-        lam = self.profile.max_rate()
-        k_gap, k_acc = jax.random.split(key)
-        gaps = jax.random.exponential(k_gap, shape) / lam
-        cand = jnp.cumsum(gaps.astype(jnp.float64), axis=-1)
-        u = jax.random.uniform(k_acc, shape)
-        accept = u * lam <= self.profile.rate(cand)
-        times = jnp.sort(jnp.where(accept, cand, PAD_TIME), axis=-1)
-        coverage = cand[..., -1]
-        return times, coverage
+        return thin_arrivals(
+            key, shape, self.profile.max_rate(), self.profile.rate
+        )
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1186,10 +1186,13 @@ def sweep(
     max_sim = float(max(sim_vals))
 
     from repro.core.simulator import (
+        check_coverage,
         draw_crash_uniforms,
         draw_reliability_stream,
         draw_workload_samples,
+        stage_draw_cells,
     )
+    from repro.core.drawplan import lower_staged
 
     n_steps = (
         int(steps)
@@ -1216,11 +1219,29 @@ def sweep(
         # prestamped regardless of the base arrival process
         prestamped = True
     # ---- host staging: the draw stacks (or fused key rows) and the
-    # per-row columns; enqueued device programs ride inside the span
-    with _span("repro.sweep.stage"):
+    # per-row columns; enqueued device programs ride inside the span.
+    # Without a retry table or a crash stream, the draw cells are staged
+    # by one device program when their processes lower to it (see
+    # simulator.stage_draw_cells); the span's ``batched`` says which.
+    # ``coverage`` then checks each draw row's reach, reduced on the
+    # device, once the caller has the vector.
+    with _span("repro.sweep.stage", cells=D) as stage_span:
+        lowered = coverage = None
+        if not fused_mode and retries == 0 and not crashes_on:
+            lowered = lower_staged(draw_cfgs)
+        stage_span.set_metadata(batched=int(lowered is not None))
         bufs = ()
         fplan = krows = pvals_list = None
-        if fused_mode:
+        if lowered is not None:
+            kinds, params = lowered
+            bufs, cover = stage_draw_cells(
+                key, params, kinds=kinds, replicas=R, n=n_steps,
+                fail=rel is not None,
+            )
+            coverage = functools.partial(
+                check_coverage, cover, max_sim, stamped=prestamped
+            )
+        elif fused_mode:
             from repro.core import drawplan as dpmod
 
             plans, pvals_list = [], []
@@ -1408,14 +1429,14 @@ def sweep(
                 _scan_dispatch(
                     scfg, scn_s, thr_rows, sim_rows, skip_rows, smp, R,
                     prestamped, plan, rely_rows=rely_rows, fused=fused_scan,
-                    fault_rows=fault_rows,
+                    fault_rows=fault_rows, coverage=coverage,
                 )
             )
         else:
             res = _block_cells(
                 scn_s, thr_rows, sim_rows, skip_rows, smp, R, prestamped,
                 bspec, plan, rely_rows=rely_rows, fused=fused_block,
-                fault_rows=fault_rows,
+                fault_rows=fault_rows, coverage=coverage,
             )
             collectors.append(lambda res=res: res)
         if "window_bounds" not in static_names and scn_s.window_bounds:
@@ -1537,20 +1558,9 @@ def _warn_nonfinite(axes: dict, ok: np.ndarray) -> None:
     )
 
 
-def _scan_cells(
-    scfg, scn_s, thr_rows, sim_rows, skip_rows, samples, R, prestamped, plan,
-    rely_rows=None, fused=None, fault_rows=None,
-):
-    """One f64 sweep launch → per-cell summaries (dispatch + drain)."""
-    return _scan_dispatch(
-        scfg, scn_s, thr_rows, sim_rows, skip_rows, samples, R, prestamped,
-        plan, rely_rows=rely_rows, fused=fused, fault_rows=fault_rows,
-    )()
-
-
 def _scan_dispatch(
     scfg, scn_s, thr_rows, sim_rows, skip_rows, samples, R, prestamped, plan,
-    rely_rows=None, fused=None, fault_rows=None,
+    rely_rows=None, fused=None, fault_rows=None, coverage=None,
 ):
     """Enqueue one f64 sweep launch; return a zero-arg collector.
 
@@ -1567,10 +1577,15 @@ def _scan_dispatch(
     is padded (with copies of row 0, sliced off afterwards) to a multiple
     of the device count.  Rows are independent, so every real cell is
     bitwise-identical to the single-device launch.
+
+    ``coverage`` (batched staging's check of its prestamped rows) runs in
+    the collector after the fetch, so a deferred sweep raises from
+    ``result()``; gap rows are checked on the final clock there.
     """
     from repro.core.simulator import (
         SimulationSummary,
         WindowedMetrics,
+        check_coverage,
         sweep_executable,
     )
 
@@ -1635,11 +1650,10 @@ def _scan_dispatch(
         with _span("repro.sweep.fetch"):
             acc_h = jax.tree.map(lambda x: np.asarray(x)[:C], acc)
             t_h = np.asarray(t_last)[:C]
-        if not prestamped and (t_h < sim_rows).any():
-            raise RuntimeError(
-                "pre-drawn arrivals ended before sim_time "
-                f"(min final t {t_h.min():.1f}); pass a larger `steps`"
-            )
+        if not prestamped:
+            check_coverage(t_h, sim_rows, stamped=False)
+        elif coverage is not None:
+            coverage()
         if acc_h["overflow"].sum() > 0:
             raise RuntimeError(
                 "instance-pool overflow during sweep; raise Scenario.slots"
@@ -1903,16 +1917,23 @@ def _block_launch(
 
 def _block_cells(
     scn_s, thr_rows, sim_rows, skip_rows, samples, R, prestamped, bspec, plan,
-    rely_rows=None, fused=None, fault_rows=None,
+    rely_rows=None, fused=None, fault_rows=None, coverage=None,
 ):
     """One f32 block-engine launch → per-cell summaries.
 
     Windowed metrics run in-kernel (irregular grids included, the window
     boundaries being traced rows) and produce full per-cell
     :class:`WindowedMetrics` — counts *and* the per-window ∫running/∫idle
-    instance-time integrals — exactly like the f64 scan path.
+    instance-time integrals — exactly like the f64 scan path.  The
+    coverage guard runs before the launch: ``coverage`` (batched staging's
+    check of the coverage it reduced on the device) where given, else on
+    the staged gaps summed on the host.
     """
-    from repro.core.simulator import SimulationSummary, WindowedMetrics
+    from repro.core.simulator import (
+        SimulationSummary,
+        WindowedMetrics,
+        check_coverage,
+    )
     from repro.kernels.faas_event_step import (
         ACC_COLS,
         FAULT_COLS,
@@ -1937,7 +1958,10 @@ def _block_cells(
             # extras (same order the scan consumes)
             crash_u, extras = extras[-1], extras[:-1]
         n_draws = dts.shape[1]
-        if not prestamped:
+        if coverage is not None:
+            with _span("repro.sweep.guard"):
+                coverage()
+        elif not prestamped:
             # Coverage guard on the REAL draws (before any padding): every
             # row's arrivals must reach its horizon, else the grid would be
             # silently truncated.  f64 sum of the f32 gaps — the padded
@@ -1945,11 +1969,7 @@ def _block_cells(
             # guarded on the kernel's own final clock after the launch.)
             with _span("repro.sweep.guard"):
                 covered = np.asarray(dts, np.float64).sum(axis=1)
-            if (covered < sim_rows).any():
-                raise RuntimeError(
-                    "pre-drawn arrivals ended before sim_time "
-                    f"(min final t {covered.min():.1f}); pass a larger `steps`"
-                )
+            check_coverage(covered, sim_rows, stamped=False)
     wb = scn_s.window_bounds
     W = len(wb) - 1 if wb else 0
     window_rows = None

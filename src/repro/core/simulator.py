@@ -50,10 +50,14 @@ import numpy as np
 
 from repro.core.execution import register_backend, register_engine
 from repro.core.processes import (
+    RAW_DRAWS,
     ArrivalTimeProcess,
     ExpSimProcess,
     SimProcess,
     absolute_times_from_gaps,
+    finish_sample,
+    piecewise_rate,
+    thin_arrivals,
 )
 from repro.core.reliability import NO_CHILD, build_attempt_table
 
@@ -368,6 +372,29 @@ def histogram_update(hist, alive, busy_until, exp_threshold, lo, hi):
 # ---------------------------------------------------------------------------
 
 
+def check_coverage(covered, sim_time, *, stamped: bool) -> None:
+    """Raise where an arrival row ends before its horizon.
+
+    ``covered`` is how far each row's arrivals reach — a timestamp
+    stream's coverage (``stamped``), else the final clock or the f64 sum
+    of a row's gaps — and ``sim_time`` the horizon, a scalar or one per
+    row.  A short row would silently truncate its cell.
+    """
+    covered = np.asarray(covered)
+    if not (covered < sim_time).any():
+        return
+    if stamped:
+        raise RuntimeError(
+            "arrival-stream coverage ended before sim_time "
+            f"(min coverage {covered.min():.1f} < {sim_time}); "
+            "pass a larger `steps`"
+        )
+    raise RuntimeError(
+        "pre-drawn arrivals ended before sim_time "
+        f"(min final t {covered.min():.1f}); pass a larger `steps`"
+    )
+
+
 def draw_workload_samples(cfg: Scenario, key: Array, replicas: int, n: int):
     """Draw the (arrivals, warm, cold) sample buffers for ``n`` steps.
 
@@ -382,13 +409,7 @@ def draw_workload_samples(cfg: Scenario, key: Array, replicas: int, n: int):
     ap = cfg.arrival_process
     if isinstance(ap, ArrivalTimeProcess):
         arr, coverage = ap.arrival_times(k1, (replicas, n))
-        cov = np.asarray(coverage)
-        if (cov < cfg.sim_time).any():
-            raise RuntimeError(
-                "arrival-stream coverage ended before sim_time "
-                f"(min coverage {cov.min():.1f} < {cfg.sim_time}); "
-                "pass a larger `steps`"
-            )
+        check_coverage(coverage, cfg.sim_time, stamped=True)
     else:
         arr = ap.sample(k1, (replicas, n))
     warms = cfg.warm_service_process.sample(k2, (replicas, n))
@@ -403,6 +424,51 @@ _RELY_SALT_JITTER = 1013
 _RELY_SALT_WARM = 1014
 _RELY_SALT_COLD = 1015
 _RELY_SALT_FAIL = 1016
+
+
+@functools.partial(
+    jax.jit, static_argnames=("kinds", "replicas", "n", "fail")
+)
+def stage_draw_cells(key, params, *, kinds, replicas, n, fail=False):
+    """A sweep's draw cells staged in one compiled device program.
+
+    The batched form of calling :func:`draw_workload_samples` once per
+    cell along the sweep's chained ``key, sub = split(key)`` walk (with
+    :func:`draw_reliability_stream`'s failure uniforms when ``fail``):
+    bitwise the same draws, from one device program with no host sync.
+    ``kinds`` and ``params`` are what ``drawplan.lower_staged`` made of
+    the cells.  Returns ``(samples, coverage)``: the ``[D·R, n]`` sample
+    stacks and each row's coverage ``[D·R]`` reduced on the device — the
+    f64 sum of its f32 gaps, or an NHPP row's last candidate time — for
+    the caller to check against the horizon once it is fetched.  One
+    compile per (families, D, R, n, profile steps); every parameter is
+    traced.
+    """
+    TRACE_COUNTS["stage_draw_cells"] += 1
+    shape = (replicas, n)
+    arrival, warm, cold = kinds
+
+    def cell(key, p):
+        key, sub = jax.random.split(key)
+        k1, k2, k3 = jax.random.split(sub, 3)
+        if arrival == "nhpp":
+            rate = functools.partial(piecewise_rate, p["edges"], p["rates"])
+            arr, cover = thin_arrivals(k1, shape, p["arrival"][0], rate)
+        else:
+            arr = finish_sample(RAW_DRAWS[arrival](k1, shape, *p["arrival"]))
+            cover = arr.astype(jnp.float64).sum(axis=1)
+        out = (
+            arr,
+            finish_sample(RAW_DRAWS[warm](k2, shape, *p["warm"])),
+            finish_sample(RAW_DRAWS[cold](k3, shape, *p["cold"])),
+        )
+        if fail:
+            kf = jax.random.fold_in(sub, _RELY_SALT_FAIL)
+            out += (jax.random.uniform(kf, shape, dtype=jnp.float32),)
+        return key, (out, cover)
+
+    _, (out, cover) = jax.lax.scan(cell, key, params)
+    return tuple(x.reshape((-1, n)) for x in out), cover.reshape(-1)
 
 
 def draw_crash_uniforms(key: Array, replicas: int, n: int):

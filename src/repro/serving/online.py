@@ -23,7 +23,8 @@ shape that reaches the jitted sweep is pinned at construction time:
 
 ``TRACE_COUNTS["online_tick"]`` accumulates the number of *new traces*
 each tick caused (the delta of every underlying trace counter around the
-dispatch): 1 on the warmup tick, 0 in steady state.
+dispatch): on the warmup tick 1 for the sweep, plus 1 the first time
+the draw-staging program is traced at those shapes; 0 in steady state.
 
 Ticks overlap simulation with ingestion via JAX async dispatch:
 ``sweep(deferred=True)`` enqueues tick *t*'s device call and returns
@@ -349,8 +350,9 @@ class OnlineWhatIfService:
         Dispatches this tick's sweep and (with ``overlap``) drains the
         *previous* tick's — returns ``None`` on the first overlapped
         tick.  The sweep's trace-count delta lands in
-        ``TRACE_COUNTS["online_tick"]``: 1 for the warmup tick, 0 in
-        steady state.
+        ``TRACE_COUNTS["online_tick"]``: on the warmup tick 1 for the
+        sweep, plus 1 the first time the draw-staging program is traced
+        at those shapes; 0 in steady state.
         """
         cfg = self.config
         stall = None

@@ -153,3 +153,24 @@ def test_par_sweep_compiles_for_v5e(one_chip, slots):
         slots=slots, block_k=BK, interpret=False,
     )
     _compile(fe.par_sweep_pallas, (row, samp, samp, samp), kw)
+
+
+
+def test_draw_staging_compiles_for_v5e(one_chip):
+    """The batched draw staging of the benchmark's sweep grid: 256
+    exponential cells x 16 replicas x 15750 draws.  (The step-profile
+    NHPP variant takes minutes to compile here, so the chip's own runs
+    cover it.)"""
+    from repro.core.simulator import stage_draw_cells
+
+    D, reps, n = 256, 16, 15750
+    params = {
+        s: _spec(one_chip, D, 2, dtype=jnp.float64)
+        for s in ("arrival", "warm", "cold")
+    }
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip)
+    compiled = stage_draw_cells.lower(
+        key, params, kinds=("exp", "exp", "exp"), replicas=reps, n=n
+    ).compile()
+    stacks = 3 * D * reps * n * 4  # three float32 [D·R, n] stacks
+    assert compiled.memory_analysis().output_size_in_bytes >= stacks
