@@ -20,28 +20,33 @@ import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-def readings(workload: str, seeds: list, seconds: float, *, control_seeds: int = 3) -> list:
+
+def readings(workload: str, seeds: list, seconds: float, *, control_seeds: int = 3,
+             root: str = ROOT) -> list:
     """``[{"seed", "program", "control"}]``: the compared numbers of the
     program on each seed, and of the control on the first
-    ``control_seeds`` of them."""
+    ``control_seeds`` of them, from the checkout at ``root``."""
     import numpy as np
 
     import harness
 
-    spec = harness.load_spec()
-    cell, config, traffic = harness.load_cell(spec, workload)
+    spec = harness.load_spec(root)
+    cell, config, traffic = harness.load_cell(spec, workload, root)
     harness.gate(int(cell["chips"]))
     harness.enable_cache()
-    sys.path.insert(0, os.path.join(harness.ROOT, "src"))
+    sys.path.insert(0, os.path.join(root, "src"))
     import mixes
 
+    # loaded before the workers start, so that its reference imports there
+    kind = mixes.load_kind(traffic["kind"], root)
     control = mixes.control_precision(config, traffic)
     pool = harness.reference_pool()
     out = []
     try:
         for seed in seeds:
-            mix = mixes.build(config, traffic, seed)
+            mix = kind.Mix(config, traffic, seed)
             warm = int(traffic.get("warmup_requests", 1))
             mix.prepare(warm + int(traffic.get("max_requests", 0)))
             for i in range(warm):

@@ -2,12 +2,14 @@
 
 The cell's entry in ``BENCHMARK.json`` names a configuration and a
 traffic mix; both are data files found by name
-(``bench/configs/<config>.json``, ``bench/traffic/<mix>.json``), read by
-the one request generator (``bench/mixes.py``).  Each per-layer metric
-is a reader of its own, ``bench/metrics/<metric>.py``, with a function
-``read(ctx)`` that returns a number or ``None`` where it finds nothing to
-read.  A new cell or metric is new files and entries; no code here
-changes.
+(``bench/configs/<config>.json``, ``bench/traffic/<mix>.json``).  The
+traffic names its kind, a file of its own, ``bench/kinds/<kind>.py``,
+that turns the two into requests and checks them (``bench/mixes.py``).
+Each per-layer metric is a reader of its own,
+``bench/metrics/<metric>.py``, with a function ``read(ctx)`` that returns
+a number or ``None`` where it finds nothing to read.  All are found by
+name in the checkout at ``root``.  A new cell or metric is new files and
+entries, and so is a new kind; no code here changes.
 
 A run: gate on the device, turn on the compilation cache inside the
 checkout, build the traffic from the seed, warm up (the whole of
@@ -268,7 +270,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, t_process: flo
     sys.path.insert(0, os.path.join(root, "src"))
     import mixes  # noqa: E402  (imports the program)
 
-    mix = mixes.build(config, traffic, seed)
+    mix = mixes.build(config, traffic, seed, root)
     warm = int(traffic.get("warmup_requests", 1))
     mix.prepare(warm + int(traffic.get("max_requests", 0)))
     t_build = time.perf_counter()

@@ -1,15 +1,18 @@
 """Every cell of BENCHMARK.json runs end to end on the CPU at a tiny size:
 its traffic builds, requests run, the work it counts is what the requests
-asked for, and the last line is the contract's result."""
+asked for, the last line is the contract's result, and the reference
+gives the same numbers in the run's worker pool as in one process.  Each
+cell's kind brings its test hooks (``bench_helpers``)."""
 
 import json
 
 import pytest
 
-from bench_helpers import shrink, spec, steer, run_tiny, workloads
+from bench_helpers import (ROOT, check_twice, hooks, hooked_workloads, kind_of, run_tiny,
+                           shrink, spec, steer, workloads)
 
 
-@pytest.mark.parametrize("workload", workloads())
+@pytest.mark.parametrize("workload", hooked_workloads())
 def test_cell_runs_tiny(workload, monkeypatch, capsys):
     steer(monkeypatch)
     result, out, err = run_tiny(workload, capsys)
@@ -32,7 +35,7 @@ def test_cell_runs_tiny(workload, monkeypatch, capsys):
     assert [ln.split()[1] for ln in err[-len(last["checks"]):]] == list(last["checks"])
 
 
-@pytest.mark.parametrize("workload", workloads())
+@pytest.mark.parametrize("workload", hooked_workloads())
 def test_work_count_is_what_requests_ask(workload):
     import mixes
     import harness
@@ -40,15 +43,46 @@ def test_work_count_is_what_requests_ask(workload):
     s = spec()
     cell, config, traffic = harness.load_cell(s, workload)
     shrink(config, traffic)
-    mix = mixes.build(config, traffic, 7)
-    if traffic["kind"] == "sweep":
-        rates = mixes.axis_values(traffic["axes"]["arrival_rate"])
-        n_thr = len(mixes.axis_values(traffic["axes"]["expiration_threshold"]))
-        want = n_thr * traffic["replicas"] * traffic["sim_time"] * sum(rates)
-    else:
-        want = 0.0
+    mix = mixes.build(config, traffic, 7, ROOT)
+    want = hooks(traffic["kind"]).asked_work(config, traffic)
     assert mix.work(0) == pytest.approx(want, rel=1e-12)
     assert mix.work(5) == mix.work(0)
+
+
+@pytest.mark.parametrize("workload", hooked_workloads())
+def test_reference_pool_gives_the_same_numbers(workload, monkeypatch, capsys):
+    """The chip's path: the reference in spawned workers, which import it by
+    name, compares the same numbers as the reference in this process."""
+    import harness
+
+    steer(monkeypatch)
+    monkeypatch.setattr(harness, "POOL_SIZE", 2)
+    pairs = check_twice(monkeypatch)
+    result, _, _ = run_tiny(workload, capsys)
+    assert result["correct"] is True, result["checks"]
+    [(pooled, in_pool, here)] = pairs
+    assert pooled and in_pool == here
+
+
+def test_every_cell_kind_has_test_hooks():
+    missing = sorted({kind_of(w) for w in workloads()} - {kind_of(w) for w in hooked_workloads()})
+    for kind in missing:
+        hooks(kind)  # raises, naming the file to add
+
+
+def test_hooks_of_an_unknown_kind_name_the_file_to_add():
+    with pytest.raises(AssertionError, match="add tests/bench/kinds/no-such-kind.py"):
+        hooks("no-such-kind")
+
+
+def test_an_unknown_kind_names_the_kinds_found():
+    import mixes
+
+    found = mixes.kinds(ROOT)
+    assert {"online", "sweep"} <= set(found)
+    with pytest.raises(ValueError, match="unknown traffic kind 'no-such-kind'") as e:
+        mixes.build({}, {"kind": "no-such-kind"}, 7, ROOT)
+    assert str(e.value).endswith(f"kinds: {found}")
 
 
 def test_gate_refuses_a_machine_without_a_tpu():

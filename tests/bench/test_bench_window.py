@@ -62,18 +62,25 @@ def _retrace_in_window(monkeypatch, what):
     import mixes
     from repro.core import scenario
 
-    orig = mixes.SweepMix.request
+    build = mixes.build
 
-    def request(self, i):
-        out = orig(self, i)
-        if i >= 1:
-            if what == "counter":
-                scenario.TRACE_COUNTS["bench_test_retrace"] += 1
-            else:
-                jax.jit(lambda x: x * (i + 0.5))(jnp.ones(3)).block_until_ready()
-        return out
+    def build_retracing(*a, **k):
+        mix = build(*a, **k)
+        orig = mix.request
 
-    monkeypatch.setattr(mixes.SweepMix, "request", request)
+        def request(i):
+            out = orig(i)
+            if i >= 1:
+                if what == "counter":
+                    scenario.TRACE_COUNTS["bench_test_retrace"] += 1
+                else:
+                    jax.jit(lambda x: x * (i + 0.5))(jnp.ones(3)).block_until_ready()
+            return out
+
+        mix.request = request
+        return mix
+
+    monkeypatch.setattr(mixes, "build", build_retracing)
 
 
 @pytest.mark.parametrize("what", ["counter", "compile"])
