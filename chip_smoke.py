@@ -270,9 +270,8 @@ def _pallas_custom_calls(rows: int, steps: int, slots: int) -> int:
     from repro.core.execution import Execution
     from repro.kernels import faas_event_step as fe
 
-    R = -(-rows // fe.BLOCK_R) * fe.BLOCK_R
+    R, K = rows, steps  # the kernel program pads both itself
     bk = Execution().resolved_block_k(steps)
-    K = -(-steps // bk) * bk
     S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
     text = fe.faas_sweep_pallas.lower(
         S(R, slots), S(R, slots), S(R, slots), S(R), S(R),

@@ -443,38 +443,67 @@ class Execution:
 
     # ---- block-kernel chunking -----------------------------------------
     def resolved_block_k(self, n_steps: int) -> int:
-        """The concrete arrival-chunk size for an ``n_steps``-long stream.
+        """The concrete event-chunk size for an ``n_steps``-long stream of
+        the single-function block kernel (rows on lanes, events on
+        sublanes; DESIGN.md §5).
 
-        An explicit ``block_k`` is honoured (clamped to the stream
-        length); ``block_k=None`` derives it from ``n_steps`` and the
-        :data:`BLOCK_K_VMEM_BUDGET` for the three ``[block_r, block_k]``
-        f32 sample blocks — ``min(K, budget)``, so short streams run as
-        one chunk and long ones chunk at the VMEM ceiling.  Either is
-        rounded up to whole 128-lane tiles (:func:`lane_chunk`).  The launcher
-        pads ``K`` up to a ``block_k`` multiple either way (the
-        ``K % block_k == 0`` rule), so every choice is semantics-free;
-        engines report the chosen value on the result's resolved plan.
+        An explicit ``block_k`` is honoured (clamped to the stream length,
+        rounded up to whole :data:`SUBLANE` tiles).  ``block_k=None``
+        splits the stream into the fewest chunks that fit
+        :data:`BLOCK_K_VMEM_BUDGET` (three ``[block_k, 128]`` f32 sample
+        blocks) and balances them, so the padded stream is at most
+        ``n_steps`` plus 8 events a chunk.  The launcher pads ``K`` up to
+        a ``block_k`` multiple either way, so every choice is
+        semantics-free; engines report the chosen value on the result's
+        resolved plan.
         """
+        if self.block_k is not None:
+            return sublane_chunk(self.block_k, n_steps)
+        n = max(int(n_steps), 1)
+        chunks = -(-n // _AUTO_SUBLANE_BLOCK_K_MAX)
+        return sublane_chunk(-(-n // chunks), n)
+
+    def resolved_lane_block_k(self, n_steps: int) -> int:
+        """The arrival-chunk size of the lane-major kernels (fleet, par),
+        whose ``[8, block_k]`` sample blocks are whole 128-lane tiles:
+        ``block_k`` (or the VMEM ceiling :data:`_AUTO_BLOCK_K_MAX`)
+        clamped to the stream and rounded up by :func:`lane_chunk`."""
         return lane_chunk(
-            _AUTO_BLOCK_K_MAX if self.block_k is None else self.block_k, n_steps
+            _AUTO_BLOCK_K_MAX if self.block_k is None else self.block_k,
+            n_steps,
         )
 
 
-# TPU vreg lane width: block-kernel chunks are whole lane tiles
+# TPU vreg tile: 8 sublanes × 128 lanes of 32-bit words
 LANE = 128
+SUBLANE = 8
 
 # Auto block_k VMEM budget: bytes allowed for the three f32 sample blocks
-# of one replica-row block (BLOCK_R=8 rows).  1 MiB / (3 · 8 · 4 B) =
-# 10922 columns, rounded down to a 128-lane multiple.
+# of one replica block.  The single-function kernel's blocks are
+# [block_k, 128] (1 MiB / (3 · 128 · 4 B) = 682 events, rounded down to
+# whole sublane tiles); the lane-major kernels' are [8, block_k] (10922
+# columns, rounded down to whole lane tiles).
 BLOCK_K_VMEM_BUDGET = 1 << 20
+_AUTO_SUBLANE_BLOCK_K_MAX = (
+    BLOCK_K_VMEM_BUDGET // (3 * LANE * 4) // SUBLANE * SUBLANE
+)
 _AUTO_BLOCK_K_MAX = (BLOCK_K_VMEM_BUDGET // (3 * 8 * 4)) // LANE * LANE
+
+
+def sublane_chunk(block_k: int, n_steps: int) -> int:
+    """``block_k`` clamped to an ``n_steps``-long stream and rounded up to
+    whole :data:`SUBLANE` tiles — the single-function kernel reads each
+    event's samples as one sublane row; the launcher's inert padding
+    absorbs the extra events."""
+    k = min(int(block_k), max(int(n_steps), 1))
+    return -(-k // SUBLANE) * SUBLANE
 
 
 def lane_chunk(block_k: int, n_steps: int) -> int:
     """``block_k`` clamped to an ``n_steps``-long stream and rounded up to
-    whole :data:`LANE` tiles — the Pallas kernels read each event's
-    samples from its aligned lane tile; the launchers' inert padding
-    absorbs the extra events."""
+    whole :data:`LANE` tiles — the lane-major Pallas kernels read each
+    event's samples from its aligned lane tile; the launchers' inert
+    padding absorbs the extra events."""
     k = min(int(block_k), max(int(n_steps), 1))
     return -(-k // LANE) * LANE
 

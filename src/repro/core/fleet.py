@@ -997,7 +997,7 @@ def _block_fleet_cells(fleet, staged, cells, plan, bspec, replicas):
         slots=fleet.slots,
         queue_depth=fleet.queue_depth,
         prestamped=staged["prestamped"],
-        block_k=plan.resolved_block_k(K),
+        block_k=plan.resolved_lane_block_k(K),
         **fault_kw,
     )
     acc_cols = FLEET_ACC_COLS + (3 if flt is not None else 0)

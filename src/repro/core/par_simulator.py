@@ -383,7 +383,7 @@ def _run_block_par(scn, key, plan, replicas, steps):
             jnp.asarray(dts, jnp.float32),
             jnp.asarray(warms, jnp.float32),
             jnp.asarray(colds, jnp.float32),
-            block_k=plan.resolved_block_k(n),
+            block_k=plan.resolved_lane_block_k(n),
             max_concurrency=scn.max_concurrency,
             concurrency=scn.concurrency_value,
             slots=scn.slots,

@@ -1737,8 +1737,8 @@ def _block_sharded_executable(backend: str, mesh, kw_items: tuple):
     (axis ``"grid"``) splits the flattened row axis, each device runs the
     same row launcher on its contiguous slice (rows are independent, so
     per-cell results are bitwise-identical to the unsharded launch).  The
-    caller pads the row axis to a multiple of ``lcm(BLOCK_R, devices)``
-    so every shard is whole replica-blocks.  Cached per (backend, mesh,
+    caller pads the row axis so every shard is whole replica blocks of
+    the kernel (:func:`_block_launch`).  Cached per (backend, mesh,
     static launch config); traces pinned by
     ``TRACE_COUNTS["sweep_block_sharded"]``.
     """
@@ -1785,19 +1785,20 @@ def _block_launch(
     traced sweep axes); ``window_rows`` is the optional ``[C, W+1]`` traced
     window-boundary matrix (irregular grids welcome).  ``dts`` rows are
     gaps, or absolute times when ``kw['prestamped']``.  When ``plan`` asks
-    for ``shard="grid"``, the row axis is padded to a multiple of
-    ``lcm(BLOCK_R, devices)`` with copies of row 0 (sliced off after) and
-    the launch runs under :func:`_block_sharded_executable`.  Returns the
-    f64 accumulator ``[C, cols]`` after the overflow guard.
+    for ``shard="grid"``, the row axis is padded with copies of row 0
+    (sliced off after) so that every device's shard is whole replica
+    blocks of the kernel, and the launch runs under
+    :func:`_block_sharded_executable`.  The launch span carries the
+    kernel's blocking (:func:`~repro.kernels.faas_event_step.sweep_layout`
+    of one device's rows).  Returns the f64 accumulator ``[C, cols]``
+    after the overflow guard.
     """
-    import math
-
     # kernel imports stay local so the default scan backend keeps core
     # imports light; NEG is the kernel's dead-slot sentinel
     from repro.kernels.faas_event_step import (
-        BLOCK_R,
         NEG as _F32_NEG,
         _pad_rows,
+        sweep_layout,
     )
 
     if scn.routing != "newest":
@@ -1807,8 +1808,9 @@ def _block_launch(
         )
     if fused is not None:
         C = len(np.asarray(t_exp))
+        n_steps = int(fused["n_steps"])
     else:
-        C = dts.shape[0]
+        C, n_steps = dts.shape
         dts, warms, colds = (
             jnp.asarray(dts, jnp.float32),
             jnp.asarray(warms, jnp.float32),
@@ -1854,7 +1856,7 @@ def _block_launch(
         # host-side gap sum for the caller's coverage guard.
         if window_rows is not None:
             kw = dict(kw, window_bounds=window_rows)
-        with _span("repro.sweep.launch"):
+        with _span("repro.sweep.launch", **sweep_layout(C, n_steps, block_k)):
             acc, t_last = bspec.launch(
                 *args, fused=fused, block_k=block_k, **rely_kw, **kw
             )
@@ -1878,7 +1880,9 @@ def _block_launch(
                 "drop shard='grid' or use backend='scan'"
             )
         mesh = plan.mesh()
-        pad = (-C) % math.lcm(BLOCK_R, int(mesh.devices.size))
+        n_dev = int(mesh.devices.size)
+        layout = sweep_layout(-(-C // n_dev), n_steps, block_k)
+        pad = layout["rows"] * n_dev - C
         if window_rows is not None:
             args = args + (window_rows,)
         if pad:
@@ -1896,7 +1900,7 @@ def _block_launch(
                 )
             ),
         )
-        with _span("repro.sweep.launch"):
+        with _span("repro.sweep.launch", **layout):
             out = fn(*args)
         with _span("repro.sweep.fetch"):
             acc = np.asarray(out, np.float64)[:C]
@@ -1904,7 +1908,7 @@ def _block_launch(
         launch_kw = dict(kw, block_k=block_k, **rely_kw, **fault_kw)
         if window_rows is not None:
             launch_kw["window_bounds"] = window_rows
-        with _span("repro.sweep.launch"):
+        with _span("repro.sweep.launch", **sweep_layout(C, n_steps, block_k)):
             out = bspec.launch(*args, **launch_kw)
         with _span("repro.sweep.fetch"):
             acc = np.asarray(out, np.float64)

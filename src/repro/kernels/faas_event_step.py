@@ -4,16 +4,19 @@ to blocks of Monte-Carlo replicas with the instance pool resident in VMEM.
 This is the paper's event-processing loop adapted to the TPU memory
 hierarchy: instead of a per-event HBM round-trip of the pool state (the
 ``lax.scan`` formulation's behaviour on TPU), each kernel instance keeps its
-``[R_blk, M]`` pool slab in VMEM and sequentially applies ``K_blk`` arrivals
-per grid step — HBM traffic collapses to (samples in + final state and
-accumulators out), i.e. ``O(R·K)`` instead of ``O(R·K·M)``.
+block of pools in VMEM and sequentially applies ``K_blk`` arrivals per grid
+step — HBM traffic collapses to (samples in + final state and accumulators
+out), i.e. ``O(R·K)`` instead of ``O(R·K·M)``.
 
 Grid layout (DESIGN.md §5): ``(R // block_r, K // block_k)`` with the
 arrival-chunk axis innermost.  The state/accumulator output blocks are
 indexed by the replica axis only, so they stay pinned in VMEM while the
 ``k`` axis advances — the standard TPU revisited-output accumulation
 pattern — and are initialised from the input state at ``k == 0`` via
-``pl.when``.
+``pl.when``.  The single-function kernel lays replica rows on the 128
+lanes (pools ``[M, 128]``, samples ``[block_k, 128]``, events on
+sublanes); the fleet and par kernels keep ``BLOCK_R`` rows on sublanes
+with slots and events on lanes.
 
 Precision domain: the kernel state is f32 (TPU has no f64 VPU), so it is
 the *throughput* engine for many-replica/many-cell what-if sweeps over
@@ -55,14 +58,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.execution import LANE, lane_chunk, register_backend
+from repro.core.execution import (
+    LANE,
+    SUBLANE,
+    lane_chunk,
+    register_backend,
+    sublane_chunk,
+)
 from repro.core import drawplan as dp
 
 NEG = -1e30
 
-# replica-block row granularity of the sweep launcher (rows are padded to
-# a multiple of this before the kernel grid is formed)
+# replica-block rows of the lane-major fleet and par kernels (rows are
+# padded to a multiple of this before the kernel grid is formed); a
+# fleet's functions are the rows of one block
 BLOCK_R = 8
 
 # Trace counter (kernel-local to avoid importing repro.core at call time):
@@ -143,6 +154,28 @@ def _pallas_call_32(kernel, inputs, **kw):
         return pl.pallas_call(kernel, **kw)(*inputs)
 
 
+def row_block(rows: int) -> int:
+    """Replica rows per block of the single-function kernel: one
+    :data:`LANE`-wide tile of rows, or — for fewer rows than that — the
+    whole row axis padded to :data:`SUBLANE` (DESIGN.md §5)."""
+    return min(LANE, -(-max(int(rows), 1) // SUBLANE) * SUBLANE)
+
+
+def sweep_layout(rows: int, n_steps: int, block_k: int) -> dict:
+    """How :func:`faas_sweep_pallas` blocks a ``rows × n_steps`` launch:
+    the replica block, the event chunk, and the padded row and event
+    counts it steps (``stepped`` events per row, of ``events`` asked)."""
+    rb = row_block(rows)
+    bk = sublane_chunk(block_k, n_steps)
+    return dict(
+        row_block=rb,
+        rows=-(-rows // rb) * rb,
+        block_k=bk,
+        events=int(n_steps),
+        stepped=-(-int(n_steps) // bk) * bk,
+    )
+
+
 def _faas_kernel(
     *refs,
     max_concurrency: int,
@@ -156,12 +189,14 @@ def _faas_kernel(
     crashes: bool = False,
     cap_steps: int = 0,
 ):
-    # inputs (VMEM blocks): state [Rb, M] ×3, per-row scalars [Rb, 1] ×4
-    # (+2 reliability scalars), optional window bounds [Rb, W+1] and curve
-    # grid [Rb, G], then either samples [Rb, Kb] ×3 (+1 failure uniform,
-    # +2 retry streams) or — fused draws (DESIGN.md §12) — per-row uint32
-    # key pairs [Rb, 2] ×3 and f32 dist params [Rb, 2] ×3 (+1 failure key
-    # pair); outputs are revisited across the k grid axis.
+    # Replica rows ride the lanes (DESIGN.md §5).  Inputs (VMEM blocks):
+    # state [M, Rb] ×3, per-row scalars [1, Rb] ×4 (+2 reliability),
+    # optional window bounds [W+1, Rb] and curve grid [G, Rb], then either
+    # samples [Kb, Rb] ×3 (+1 failure uniform, +2 retry streams) — events
+    # on sublanes — or, fused draws (DESIGN.md §12), per-row uint32 key
+    # pairs [2, Rb] ×3 and f32 dist params [2, Rb] ×3 (+1 failure key
+    # pair).  Outputs are revisited across the k grid axis; with retries a
+    # [Kp, Rb] activation scratch rides last.
     fused = fused_dists is not None
     (alive_in, creation_in, busy_in, t0_ref, texp_ref, tend_ref, skip_ref) = refs[:7]
     i = 7
@@ -208,14 +243,13 @@ def _faas_kernel(
     if retries:
         first_ref, child_ref = refs[i : i + 2]
         i += 2
-    act_out = doom_out = None
+    act_ref = doom_out = None
     outs = refs[i:]
-    if crashes:
-        *outs, doom_out = outs  # the doom plane rides last
     if retries:
-        alive_out, creation_out, busy_out, t_out, acc_out, act_out = outs
-    else:
-        alive_out, creation_out, busy_out, t_out, acc_out = outs
+        *outs, act_ref = outs  # the activation scratch rides last
+    if crashes:
+        *outs, doom_out = outs  # then the doom plane
+    alive_out, creation_out, busy_out, t_out, acc_out = outs
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -225,7 +259,7 @@ def _faas_kernel(
         t_out[...] = t0_ref[...]
         acc_out[...] = jnp.zeros(acc_out.shape, acc_out.dtype)
         if retries:
-            act_out[...] = jnp.zeros(act_out.shape, act_out.dtype)
+            act_ref[...] = jnp.zeros(act_ref.shape, act_ref.dtype)
         if crashes:
             # fresh pools carry no crash clock; cold starts stamp one
             doom_out[...] = jnp.full(doom_out.shape, jnp.inf, doom_out.dtype)
@@ -233,30 +267,33 @@ def _faas_kernel(
     alive = alive_out[...]
     creation = creation_out[...]
     busy = busy_out[...]
-    t = t_out[...][:, 0]
+    t = t_out[...]  # [1, Rb]
     acc0 = acc_out[...]
-    t_exp = texp_ref[...][:, 0]  # [Rb]
-    t_end = tend_ref[...][:, 0]  # [Rb]
-    skip = skip_ref[...][:, 0]  # [Rb]
-    t_to = tto_ref[...][:, 0] if reliability else None  # [Rb]
-    p_fail = pf_ref[...][:, 0] if reliability else None  # [Rb]
-    crate = crate_ref[...][:, 0] if crashes else None  # [Rb]
+    t_exp = texp_ref[...]  # [1, Rb]
+    t_end = tend_ref[...]
+    skip = skip_ref[...]
+    t_to = tto_ref[...] if reliability else None
+    p_fail = pf_ref[...] if reliability else None
+    crate = crate_ref[...] if crashes else None
     # cap_e carries a leading 0.0 edge so the segment lookup is a plain
     # count (launcher prepends it); cap_v is the per-segment ceiling
-    cap_e = cape_ref[...] if cap_steps else None  # [Rb, cap_steps]
-    cap_v = capv_ref[...] if cap_steps else None  # [Rb, cap_steps]
-    w_lo = wb_ref[...][:, :-1] if n_windows else None  # [Rb, W]
-    w_hi = wb_ref[...][:, 1:] if n_windows else None
-    g_times = grid_ref[...] if n_grid else None  # [Rb, G]
-    slot_iota = _iota(alive.shape, 1)
+    cap_e = cape_ref[...] if cap_steps else None  # [cap_steps, Rb]
+    cap_v = capv_ref[...] if cap_steps else None
+    w_lo = wb_ref[:-1, :] if n_windows else None  # [W, Rb]
+    w_hi = wb_ref[1:, :] if n_windows else None
+    g_times = grid_ref[...] if n_grid else None  # [G, Rb]
+    slot_iota = _iota(alive.shape, 0)
+    # every per-row quantity is a [1, Rb] lane row; a slot reduction is
+    # elementwise across the M/8 vregs, then one sublane reduce
+    slot_sum = lambda x: jnp.sum(x, axis=0, keepdims=True)  # noqa: E731
     if fused:
         # per-row stream keys/params live in VMEM once per chunk; draws are
-        # regenerated per event from the global counter — no [Rb, Kb] sample
-        # blocks exist anywhere (DESIGN.md §12)
-        a_keys = akey_ref[...]  # uint32 [Rb, 2]
+        # regenerated per event from the global counter — no [Kb, Rb]
+        # sample blocks exist anywhere (DESIGN.md §12)
+        a_keys = akey_ref[...]  # uint32 [2, Rb]
         w_keys = wkey_ref[...]
         c_keys = ckey_ref[...]
-        a_par = apar_ref[...]  # f32 [Rb, 2]
+        a_par = apar_ref[...]  # f32 [2, Rb]
         w_par = wpar_ref[...]
         c_par = cpar_ref[...]
         f_keys = fkey_ref[...] if reliability else None
@@ -264,36 +301,34 @@ def _faas_kernel(
         # global-position arithmetic the retries activation plane uses
         gk0 = (pl.program_id(1) * n_steps).astype(jnp.uint32)
     if retries:
-        # full-width activation plane [Rb, Ktot]: event positions are
-        # GLOBAL across k chunks, so the revisited output block spans the
-        # whole padded stream; one-hot gather/scatter keeps it vectorized
-        act0 = act_out[...]
-        k_iota = _iota(act0.shape, 1)
+        # the activation plane spans the whole padded stream (event
+        # positions are global across k chunks); row p of the scratch is
+        # event p, read once per event and scattered into by 8-row tiles
         k0 = pl.program_id(1) * n_steps
+        sub_iota = _iota((SUBLANE, alive.shape[1]), 0)
+        n_tiles = act_ref.shape[0] // SUBLANE
     if crashes:
         doom0 = doom_out[...]
 
     def step(i, carry):
         alive, creation, busy, t, acc = carry[:5]
-        rest = list(carry[5:])
-        act = rest.pop(0) if retries else None
-        doom = rest.pop(0) if crashes else None
+        doom = carry[5] if crashes else None
         if fused:
             gk = gk0 + i.astype(jnp.uint32)
-            a_u0, a_u1 = dp.event_uniforms(a_keys[:, 0], a_keys[:, 1], gk)
-            w_u0, w_u1 = dp.event_uniforms(w_keys[:, 0], w_keys[:, 1], gk)
-            c_u0, c_u1 = dp.event_uniforms(c_keys[:, 0], c_keys[:, 1], gk)
-            dt = dp.sample_dist(fused_dists[0], a_u0, a_u1, a_par[:, 0], a_par[:, 1])
-            warm_s = dp.sample_dist(fused_dists[1], w_u0, w_u1, w_par[:, 0], w_par[:, 1])
-            cold_s = dp.sample_dist(fused_dists[2], c_u0, c_u1, c_par[:, 0], c_par[:, 1])
+            a_u0, a_u1 = dp.event_uniforms(a_keys[0:1], a_keys[1:2], gk)
+            w_u0, w_u1 = dp.event_uniforms(w_keys[0:1], w_keys[1:2], gk)
+            c_u0, c_u1 = dp.event_uniforms(c_keys[0:1], c_keys[1:2], gk)
+            dt = dp.sample_dist(fused_dists[0], a_u0, a_u1, a_par[0:1], a_par[1:2])
+            warm_s = dp.sample_dist(fused_dists[1], w_u0, w_u1, w_par[0:1], w_par[1:2])
+            cold_s = dp.sample_dist(fused_dists[2], c_u0, c_u1, c_par[0:1], c_par[1:2])
             if reliability:
-                fail_i, _ = dp.event_uniforms(f_keys[:, 0], f_keys[:, 1], gk)
+                fail_i, _ = dp.event_uniforms(f_keys[0:1], f_keys[1:2], gk)
         else:
-            dt = _event_col(dt_ref, i)
-            warm_s = _event_col(warm_ref, i)
-            cold_s = _event_col(cold_ref, i)
+            dt = dt_ref[pl.ds(i, 1), :]  # [1, Rb]
+            warm_s = warm_ref[pl.ds(i, 1), :]
+            cold_s = cold_ref[pl.ds(i, 1), :]
             if reliability:
-                fail_i = _event_col(fail_ref, i)
+                fail_i = fail_ref[pl.ds(i, 1), :]
         # prestamped: the sample slot carries the absolute arrival time
         # (non-stationary/trace streams); PAD_TIME entries are inert.
         t_new = dt if prestamped else t + dt
@@ -301,27 +336,21 @@ def _faas_kernel(
         # exact integrals over the measurement window (lo, hi]
         lo = jnp.clip(t, skip, t_end)
         hi = jnp.clip(t_new, skip, t_end)
-        expire = busy + t_exp[:, None]
+        expire = busy + t_exp
         if crashes:
             # a crashed instance stops accruing run/idle time at its doom
-            stop = jnp.minimum(hi[:, None], doom)
-            run_t = jnp.clip(jnp.minimum(busy, stop) - lo[:, None], 0.0, None)
+            stop = jnp.minimum(hi, doom)
+            run_t = jnp.clip(jnp.minimum(busy, stop) - lo, 0.0, None)
             idle_t = jnp.clip(
-                jnp.minimum(expire, stop) - jnp.maximum(busy, lo[:, None]),
-                0.0,
-                None,
+                jnp.minimum(expire, stop) - jnp.maximum(busy, lo), 0.0, None
             )
         else:
-            run_t = jnp.clip(
-                jnp.minimum(busy, hi[:, None]) - lo[:, None], 0.0, None
-            )
+            run_t = jnp.clip(jnp.minimum(busy, hi) - lo, 0.0, None)
             idle_t = jnp.clip(
-                jnp.minimum(expire, hi[:, None]) - jnp.maximum(busy, lo[:, None]),
-                0.0,
-                None,
+                jnp.minimum(expire, hi) - jnp.maximum(busy, lo), 0.0, None
             )
-        run_sum = (run_t * alive).sum(axis=1)
-        idle_sum = (idle_t * alive).sum(axis=1)
+        run_sum = slot_sum(run_t * alive)
+        idle_sum = slot_sum(idle_t * alive)
 
         if n_windows:
             # per-window exact integrals over (lo_e, hi_e] ∩ window — the
@@ -329,109 +358,89 @@ def _faas_kernel(
             # the caller's own measurement grid, DESIGN.md §7)
             lo_e = jnp.minimum(t, t_end)
             hi_e = jnp.minimum(t_new, t_end)
-            wlo = jnp.maximum(w_lo, lo_e[:, None])  # [Rb, W]
-            whi = jnp.minimum(w_hi, hi_e[:, None])
-            run_w = jnp.clip(
-                jnp.minimum(busy[:, None, :], whi[:, :, None]) - wlo[:, :, None],
-                0.0,
-                None,
-            )
+            wlo = jnp.maximum(w_lo, lo_e)[:, None, :]  # [W, 1, Rb]
+            whi = jnp.minimum(w_hi, hi_e)[:, None, :]
+            run_w = jnp.clip(jnp.minimum(busy[None], whi) - wlo, 0.0, None)
             idle_w = jnp.clip(
-                jnp.minimum(expire[:, None, :], whi[:, :, None])
-                - jnp.maximum(busy[:, None, :], wlo[:, :, None]),
+                jnp.minimum(expire[None], whi) - jnp.maximum(busy[None], wlo),
                 0.0,
                 None,
-            )
-            w_run = (run_w * alive[:, None, :]).sum(axis=2)  # [Rb, W]
-            w_idle = (idle_w * alive[:, None, :]).sum(axis=2)
+            )  # [W, M, Rb]
+            w_run = (run_w * alive[None]).sum(axis=1)  # [W, Rb]
+            w_idle = (idle_w * alive[None]).sum(axis=1)
 
         if n_grid:
             # point snapshots at grid times inside (t, min(t_new, t_end)]:
             # instance counts from the pre-expiration state, exactly as the
             # temporal scan engine samples them
-            in_win = (g_times > t[:, None]) & (
-                g_times <= jnp.minimum(t_new, t_end)[:, None]
-            )  # [Rb, G]
-            live_g = (alive[:, None, :] > 0) & (
-                expire[:, None, :] > g_times[:, :, None]
-            )  # [Rb, G, M]
-            running_g = (live_g & (busy[:, None, :] > g_times[:, :, None])).sum(
-                axis=2
-            )
-            idle_g = (live_g & (busy[:, None, :] <= g_times[:, :, None])).sum(
-                axis=2
-            )
+            in_win = (g_times > t) & (g_times <= jnp.minimum(t_new, t_end))  # [G, Rb]
+            g3 = g_times[:, None, :]
+            live_g = (alive[None] > 0) & (expire[None] > g3)  # [G, M, Rb]
+            running_g = (live_g & (busy[None] > g3)).sum(axis=1)
+            idle_g = (live_g & (busy[None] <= g3)).sum(axis=1)
             g_run = jnp.where(in_win, running_g.astype(jnp.float32), 0.0)
             g_idle = jnp.where(in_win, idle_g.astype(jnp.float32), 0.0)
             g_cold = (in_win & (idle_g == 0)).astype(jnp.float32)
 
         # expirations (and crash exits: whichever clock fires first)
         exit_time = jnp.minimum(expire, doom) if crashes else expire
-        expired = (alive > 0) & (exit_time <= t_new[:, None])
+        expired = (alive > 0) & (exit_time <= t_new)
         if crashes:
             # a crash only counts when the doom instant itself is inside
             # the measured window — pad events past t_end stay inert
             crash_ok = (
-                expired
-                & (doom < expire)
-                & (doom > skip[:, None])
-                & (doom <= t_end[:, None])
+                expired & (doom < expire) & (doom > skip) & (doom <= t_end)
             )
-            n_crash = crash_ok.astype(jnp.float32).sum(axis=1)
+            n_crash = slot_sum(crash_ok.astype(jnp.float32))
         alive = jnp.where(expired, 0.0, alive)
 
         if cap_steps:
             # capacity churn: ceiling in effect at this arrival, then
             # evict the newest idle instances above it (DESIGN.md §15);
             # cap_e's leading 0-edge makes the segment index a plain count
-            seg = (cap_e <= t_new[:, None]).astype(jnp.float32).sum(axis=1) - 1.0
-            cap_col = _iota(cap_v.shape, 1)
-            cap_now = (cap_v * (cap_col == seg[:, None])).sum(axis=1)  # [Rb]
-            idle_now = (alive > 0) & (busy <= t_new[:, None])
-            over = alive.sum(axis=1) - cap_now
-            cre_a = creation[:, :, None]
-            cre_b = creation[:, None, :]
-            shape3 = (creation.shape[0], creation.shape[1], creation.shape[1])
-            ia = _iota(shape3, 1)
-            ib = _iota(shape3, 2)
+            seg = slot_sum((cap_e <= t_new).astype(jnp.float32)) - 1.0
+            cap_col = _iota(cap_v.shape, 0)
+            cap_now = slot_sum(cap_v * (cap_col == seg))  # [1, Rb]
+            idle_now = (alive > 0) & (busy <= t_new)
+            over = slot_sum(alive) - cap_now
+            # slot a on axis 0, slot b on axis 1: [M, M, Rb]
+            cre_a = creation[:, None, :]
+            cre_b = creation[None, :, :]
+            ia = slot_iota[:, None, :]
+            ib = slot_iota[None, :, :]
             newer = (cre_b > cre_a) | ((cre_b == cre_a) & (ib < ia))
             rank = (
-                (idle_now[:, None, :] & newer).astype(jnp.float32).sum(axis=2)
-            )  # [Rb, M] idle instances strictly newer than each slot
-            evict = (
-                idle_now
-                & (rank < over[:, None])
-                & (t_new <= t_end)[:, None]
-            )
-            n_evict = (
-                (evict & (t_new > skip)[:, None]).astype(jnp.float32).sum(axis=1)
-            )
+                (idle_now[None, :, :] & newer).astype(jnp.float32).sum(axis=1)
+            )  # [M, Rb] idle instances strictly newer than each slot
+            evict = idle_now & (rank < over) & (t_new <= t_end)
+            n_evict = slot_sum((evict & (t_new > skip)).astype(jnp.float32))
             alive = jnp.where(evict, 0.0, alive)
 
         # routing: newest idle instance
-        idle = (alive > 0) & (busy <= t_new[:, None])
-        best = jnp.max(jnp.where(idle, creation, NEG), axis=1)
+        idle = (alive > 0) & (busy <= t_new)
+        best = jnp.max(jnp.where(idle, creation, NEG), axis=0, keepdims=True)
         any_idle = best > NEG * 0.5
         # first slot achieving the max (tie-break by slot index, as the ref)
-        is_best = idle & (creation >= best[:, None]) & any_idle[:, None]
-        first_best = jnp.min(jnp.where(is_best, slot_iota, 1e9), axis=1)
+        is_best = idle & (creation >= best) & any_idle
+        first_best = jnp.min(
+            jnp.where(is_best, slot_iota, 1e9), axis=0, keepdims=True
+        )
 
         free = alive <= 0
-        first_free = jnp.min(jnp.where(free, slot_iota, 1e9), axis=1)
+        first_free = jnp.min(jnp.where(free, slot_iota, 1e9), axis=0, keepdims=True)
         # not free.any(): Mosaic lowers bool reductions through a float
         # proxy that widens to f64 when the caller runs with x64 on
         any_free = first_free < 1e9
-        n_alive = alive.sum(axis=1)
+        n_alive = slot_sum(alive)
 
         active = t_new <= t_end
         if retries:
             # Non-first attempts stay inert until their parent's failure /
             # timeout / rejection switched them on (inactive events still
             # advance the clock, integrate and expire — no-op arrivals).
-            is_first = _event_col(first_ref, i)
-            child = _event_col(child_ref, i)
-            gf = (k0 + i).astype(jnp.float32)  # global event position
-            act_i = jnp.where(k_iota == gf, act, 0.0).sum(axis=1)
+            is_first = first_ref[pl.ds(i, 1), :]
+            child = child_ref[pl.ds(i, 1), :]
+            act_i = act_ref[pl.ds(k0 + i, 1), :]
             active = active & ((is_first > 0) | (act_i > 0))
         counted = t_new > skip
         can_cold = (~any_idle) & (n_alive < max_concurrency) & any_free
@@ -452,19 +461,19 @@ def _faas_kernel(
         else:
             occupancy = service
         assign = is_warm | is_cold
-        sel = (slot_iota == chosen[:, None]) & assign[:, None]
-        busy = jnp.where(sel, (t_new + occupancy)[:, None], busy)
-        creation = jnp.where(sel & is_cold[:, None], t_new[:, None], creation)
-        alive = jnp.where(sel & is_cold[:, None], 1.0, alive)
+        sel = (slot_iota == chosen) & assign
+        busy = jnp.where(sel, t_new + occupancy, busy)
+        creation = jnp.where(sel & is_cold, t_new, creation)
+        alive = jnp.where(sel & is_cold, 1.0, alive)
         if crashes:
             # Exp(crash_rate) lifetime stamped at cold start (memoryless ⇒
             # hazard-equivalent); warm hits keep the instance's old doom
-            crash_i = _event_col(crashu_ref, i)
+            crash_i = crashu_ref[pl.ds(i, 1), :]
             life = -jnp.log(1.0 - crash_i) / crate
-            doom = jnp.where(
-                sel & is_cold[:, None], (t_new + life)[:, None], doom
+            doom = jnp.where(sel & is_cold, t_new + life, doom)
+            doom_chosen = jnp.min(
+                jnp.where(sel, doom, jnp.inf), axis=0, keepdims=True
             )
-            doom_chosen = jnp.min(jnp.where(sel, doom, jnp.inf), axis=1)
 
         cc = counted
         if reliability:
@@ -486,98 +495,92 @@ def _faas_kernel(
             if crashes:
                 interrupted = assign & (doom_chosen < t_new + occupancy)
             cold_resp, warm_resp = cold_s, warm_s
-        delta = jnp.stack(
-            [
-                (is_cold & cc).astype(jnp.float32),
-                (is_warm & cc).astype(jnp.float32),
-                (is_reject & cc).astype(jnp.float32),
-                run_sum,
-                idle_sum,
-                jnp.where(is_cold & cc, cold_resp, 0.0),
-                jnp.where(is_warm & cc, warm_resp, 0.0),
-                overflow.astype(jnp.float32),
-            ],
-            axis=1,
-        )
+        f32 = lambda b: b.astype(jnp.float32)  # noqa: E731
+        # the delta is a sublane stack of [1, Rb] lane rows
+        rows = [
+            f32(is_cold & cc),
+            f32(is_warm & cc),
+            f32(is_reject & cc),
+            run_sum,
+            idle_sum,
+            jnp.where(is_cold & cc, cold_resp, 0.0),
+            jnp.where(is_warm & cc, warm_resp, 0.0),
+            f32(overflow),
+        ]
         if n_windows:
             # half-open window membership [b_w, b_{w+1}) of the arrival
             # instant (windows ignore skip — the grid is the caller's own
             # measurement request)
-            onehot = (
-                (t_new[:, None] >= w_lo) & (t_new[:, None] < w_hi)
-            ) & active[:, None]
-            w_cold = (onehot & is_cold[:, None]).astype(jnp.float32)
-            w_served = (onehot & (is_cold | is_warm)[:, None]).astype(
-                jnp.float32
-            )
-            w_arr = onehot.astype(jnp.float32)  # includes rejects
-            delta = jnp.concatenate(
-                [delta, w_cold, w_served, w_arr, w_run, w_idle], axis=1
-            )
+            onehot = (t_new >= w_lo) & (t_new < w_hi) & active  # [W, Rb]
+            rows += [
+                f32(onehot & is_cold),
+                f32(onehot & (is_cold | is_warm)),
+                f32(onehot),  # includes rejects
+                w_run,
+                w_idle,
+            ]
         if n_grid:
-            delta = jnp.concatenate([delta, g_run, g_idle, g_cold], axis=1)
+            rows += [g_run, g_idle, g_cold]
         if reliability:
             if retries:
                 has_child = child < NO_CHILD_F
                 r_retry = (is_first <= 0) & active & cc
                 r_abandon = trigger & ~has_child & cc
-                # re-enqueue: one-hot scatter switches on the successor
-                # (NO_CHILD matches no column, so last attempts drop out)
-                hit = (k_iota == child[:, None]) & trigger[:, None]
-                act = jnp.where(hit, 1.0, act)
+                # re-enqueue: switch on the successor's row of the plane,
+                # one 8-event tile at a time (NO_CHILD matches no row, so
+                # last attempts drop out)
+                on = jnp.where(trigger, child, -1.0)
+
+                def scatter(j, _):
+                    base = pl.multiple_of(j * SUBLANE, SUBLANE)
+                    pos = sub_iota + base.astype(jnp.float32)
+                    tile = act_ref[pl.ds(base, SUBLANE), :]
+                    act_ref[pl.ds(base, SUBLANE), :] = jnp.where(
+                        pos == on, 1.0, tile
+                    )
+                    return 0
+
+                jax.lax.fori_loop(0, n_tiles, scatter, 0)
             else:
                 r_retry = jnp.zeros_like(trigger)
                 r_abandon = trigger & cc
-            delta = jnp.concatenate(
-                [
-                    delta,
-                    jnp.stack(
-                        [
-                            (timed_out & cc).astype(jnp.float32),
-                            (failed & cc).astype(jnp.float32),
-                            r_retry.astype(jnp.float32),
-                            r_abandon.astype(jnp.float32),
-                        ],
-                        axis=1,
-                    ),
-                ],
-                axis=1,
-            )
+            rows += [
+                f32(timed_out & cc),
+                f32(failed & cc),
+                f32(r_retry),
+                f32(r_abandon),
+            ]
         if crashes or cap_steps:
             zero = jnp.zeros_like(run_sum)
-            f_crash = n_crash if crashes else zero
-            f_evict = n_evict if cap_steps else zero
-            f_int = (
-                (interrupted & cc).astype(jnp.float32) if crashes else zero
-            )
-            delta = jnp.concatenate(
-                [delta, jnp.stack([f_crash, f_evict, f_int], axis=1)], axis=1
-            )
-        acc = acc + delta
+            rows += [
+                n_crash if crashes else zero,
+                n_evict if cap_steps else zero,
+                f32(interrupted & cc) if crashes else zero,
+            ]
+        acc = acc + jnp.concatenate(rows, axis=0)
         out = (alive, creation, busy, t_new, acc)
-        if retries:
-            out = out + (act,)
         if crashes:
             out = out + (doom,)
         return out
 
     carry0 = (alive, creation, busy, t, acc0)
-    if retries:
-        carry0 = carry0 + (act0,)
     if crashes:
         carry0 = carry0 + (doom0,)
     carry = jax.lax.fori_loop(0, n_steps, step, carry0)
     alive, creation, busy, t, acc = carry[:5]
-    rest = list(carry[5:])
-    if retries:
-        act_out[...] = rest.pop(0)
     if crashes:
-        doom_out[...] = rest.pop(0)
+        doom_out[...] = carry[5]
     alive_out[...] = alive
     creation_out[...] = creation
     busy_out[...] = busy
-    t_out[...] = t[:, None]
+    t_out[...] = t
     acc_out[...] = acc
+
+
+# VMEM the compiler may give a kernel by default (v5e's scoped limit);
+# a large explicit block_k, or a retry sweep's whole-stream activation
+# scratch, can need more
+_SCOPED_VMEM = 16 << 20
 
 
 @functools.partial(
@@ -623,7 +626,7 @@ def faas_sweep_pallas(
     cap_edges=None,  # f32 [R, E] capacity-profile step times (faults)
     cap_values=None,  # f32 [R, E+1] per-segment capacity ceilings (faults)
     max_concurrency: int,
-    block_r: int = 8,
+    block_r=None,  # replica block; None derives it (:func:`row_block`)
     block_k: int = 512,
     interpret: bool = False,
     prestamped: bool = False,
@@ -632,7 +635,7 @@ def faas_sweep_pallas(
     reliability: bool = False,
     retries: bool = False,
     fused_dists=None,  # static ("exp", ...) ×3 → in-VMEM draw generation
-    fused_k: int = 0,  # static padded event count when fused (no dts)
+    fused_k: int = 0,  # static event count when fused (no dts)
 ):
     """Run the full event loop: K arrivals in ``block_k`` chunks, pool in VMEM.
 
@@ -644,6 +647,12 @@ def faas_sweep_pallas(
     one kernel launch and one compile — and with ``prestamped=True`` the
     rows carry absolute-timestamp streams, so a sweep over *rate profiles*
     (each row thinned from its own profile) is also one launch.
+
+    The kernel runs with rows on lanes (DESIGN.md §5): inside this one
+    program every ``[R, K]`` stream is padded — rows to the replica
+    block, events to a ``block_k`` multiple, with inert fills — and
+    transposed to ``[K, R]`` in the same pass, and the results are
+    transposed back, so callers see ``[R, ...]`` throughout.
 
     ``n_windows > 0`` appends per-window metric columns over the traced
     (possibly irregular) boundary rows ``window_bounds``: cold
@@ -668,13 +677,33 @@ def faas_sweep_pallas(
             "fused draws do not serve platform faults"
         )
     R, M = alive.shape
-    K = fused_k if fused else dts.shape[1]
-    assert R % block_r == 0, (R, block_r)
-    assert K % block_k == 0, (K, block_k)
-    assert block_k <= LANE or block_k % LANE == 0, block_k  # _event_col tiles
-    t_end = jnp.broadcast_to(jnp.asarray(t_end, jnp.float32), (R,))
-    skip = jnp.broadcast_to(jnp.asarray(skip, jnp.float32), (R,))
-    grid = (R // block_r, K // block_k)
+    n = fused_k if fused else dts.shape[1]
+    rb = row_block(R) if block_r is None else block_r
+    Rp = -(-R // rb) * rb
+    K = -(-n // block_k) * block_k
+    # a chunk is whole sublane tiles, or the whole (single-chunk) stream
+    assert block_k % SUBLANE == 0 or K == block_k, block_k
+    assert rb % LANE == 0 or rb == Rp, (rb, Rp)
+
+    def lanes(x, dtype=jnp.float32):
+        """[R, X] → [X, Rp]: padded rows copy the last real one."""
+        x = jnp.asarray(x, dtype)
+        return jnp.pad(x, ((0, Rp - R), (0, 0)), mode="edge").T
+
+    def per_row(x):
+        """A per-row value (or scalar) → a [1, Rp] lane row."""
+        return lanes(jnp.broadcast_to(jnp.asarray(x, jnp.float32), (R,))[:, None])
+
+    def events(x, fill):
+        """[R, n] → [K, Rp], events on sublanes.  Fills are inert: a 1e30
+        gap or timestamp is past every ``t_end``, so padded events never
+        engage, and the other fills only keep the arithmetic finite."""
+        x = jnp.asarray(x, jnp.float32)
+        return jnp.pad(
+            x, ((0, Rp - R), (0, K - n)), constant_values=fill
+        ).T
+
+    grid = (Rp // rb, K // block_k)
     acc_cols = (
         ACC_COLS
         + WINDOW_COLS * n_windows
@@ -683,10 +712,12 @@ def faas_sweep_pallas(
         + (FAULT_COLS if crashes or cap_steps else 0)
     )
 
-    state_spec = pl.BlockSpec((block_r, M), lambda r, k: (r, 0))
-    samp_spec = pl.BlockSpec((block_r, block_k), lambda r, k: (r, k))
-    t_spec = pl.BlockSpec((block_r, 1), lambda r, k: (r, 0))
-    acc_spec = pl.BlockSpec((block_r, acc_cols), lambda r, k: (r, 0))
+    def rows_spec(n_rows):
+        return pl.BlockSpec((n_rows, rb), lambda r, k: (0, r))
+
+    state_spec = rows_spec(M)
+    t_spec = rows_spec(1)
+    samp_spec = pl.BlockSpec((block_k, rb), lambda r, k: (k, r))
 
     kernel = functools.partial(
         _faas_kernel,
@@ -703,89 +734,94 @@ def faas_sweep_pallas(
     )
     in_specs = [state_spec, state_spec, state_spec, t_spec, t_spec, t_spec, t_spec]
     inputs = [
-        alive,
-        creation,
-        busy,
-        t0[:, None],
-        t_exp[:, None],
-        t_end[:, None],
-        skip[:, None],
+        lanes(alive),
+        lanes(creation),
+        lanes(busy),
+        per_row(t0),
+        per_row(t_exp),
+        per_row(t_end),
+        per_row(skip),
     ]
     if n_windows:
-        in_specs.append(pl.BlockSpec((block_r, n_windows + 1), lambda r, k: (r, 0)))
-        inputs.append(jnp.asarray(window_bounds, jnp.float32))
+        in_specs.append(rows_spec(n_windows + 1))
+        inputs.append(lanes(window_bounds))
     if n_grid:
-        in_specs.append(pl.BlockSpec((block_r, n_grid), lambda r, k: (r, 0)))
-        inputs.append(jnp.asarray(grid_times, jnp.float32))
+        in_specs.append(rows_spec(n_grid))
+        inputs.append(lanes(grid_times))
     if reliability:
         in_specs += [t_spec, t_spec]
-        inputs += [
-            jnp.broadcast_to(jnp.asarray(t_timeout, jnp.float32), (R,))[:, None],
-            jnp.broadcast_to(jnp.asarray(p_fail, jnp.float32), (R,))[:, None],
-        ]
+        inputs += [per_row(t_timeout), per_row(p_fail)]
     if crashes:
         in_specs.append(t_spec)
-        inputs.append(
-            jnp.broadcast_to(jnp.asarray(crash_rate, jnp.float32), (R,))[:, None]
-        )
+        inputs.append(per_row(crash_rate))
     if cap_steps:
-        cap_spec = pl.BlockSpec((block_r, cap_steps), lambda r, k: (r, 0))
-        in_specs += [cap_spec, cap_spec]
+        in_specs += [rows_spec(cap_steps), rows_spec(cap_steps)]
         # prepend the implicit t=0 edge so the in-kernel segment lookup is
-        # a plain count (and the block is never zero-width for E == 0)
+        # a plain count (and the block is never zero-height for E == 0)
         inputs += [
-            jnp.concatenate(
-                [
-                    jnp.zeros((R, 1), jnp.float32),
-                    jnp.asarray(cap_edges, jnp.float32),
-                ],
-                axis=1,
+            lanes(
+                jnp.concatenate(
+                    [
+                        jnp.zeros((R, 1), jnp.float32),
+                        jnp.asarray(cap_edges, jnp.float32),
+                    ],
+                    axis=1,
+                )
             ),
-            jnp.asarray(cap_values, jnp.float32),
+            lanes(cap_values),
         ]
     if fused:
         # the entire per-row sample state: three 8-byte key pairs and three
-        # (p0, p1) param pairs — no [R, K] buffers exist anywhere
-        pair_spec = pl.BlockSpec((block_r, 2), lambda r, k: (r, 0))
-        in_specs += [pair_spec] * 6
-        inputs += [jnp.asarray(k, jnp.uint32) for k in fused_keys]
-        inputs += [jnp.asarray(p, jnp.float32) for p in fused_params]
+        # (p0, p1) param pairs — no [K, R] buffers exist anywhere
+        in_specs += [rows_spec(2)] * 6
+        inputs += [lanes(k, jnp.uint32) for k in fused_keys]
+        inputs += [lanes(p) for p in fused_params]
         if reliability:
-            in_specs.append(pair_spec)
-            inputs.append(jnp.asarray(fused_fail_keys, jnp.uint32))
+            in_specs.append(rows_spec(2))
+            inputs.append(lanes(fused_fail_keys, jnp.uint32))
     else:
         in_specs += [samp_spec, samp_spec, samp_spec]
-        inputs += [dts, warms, colds]
+        inputs += [events(dts, 1e30), events(warms, 1.0), events(colds, 1.0)]
         if reliability:
+            # padded events are inert (active=False via the 1e30 clock):
+            # fail_u=1.0 never fails (p_fail < 1)
             in_specs.append(samp_spec)
-            inputs.append(jnp.asarray(fail_u, jnp.float32))
+            inputs.append(events(fail_u, 1.0))
         if crashes:
+            # any doom a padded event stamps is past t_end, never counted;
+            # 0.0 keeps the log finite
             in_specs.append(samp_spec)
-            inputs.append(jnp.asarray(crash_u, jnp.float32))
+            inputs.append(events(crash_u, 0.0))
     if retries:
+        # is_first=0 keeps padded events inactive, NO_CHILD never scatters
         in_specs += [samp_spec, samp_spec]
-        inputs += [
-            jnp.asarray(is_first, jnp.float32),
-            jnp.asarray(child_pos, jnp.float32),
-        ]
-    out_specs = [state_spec, state_spec, state_spec, t_spec, acc_spec]
+        inputs += [events(is_first, 0.0), events(child_pos, NO_CHILD_F)]
+    out_specs = [state_spec, state_spec, state_spec, t_spec, rows_spec(acc_cols)]
     out_shape = [
-        jax.ShapeDtypeStruct((R, M), jnp.float32),
-        jax.ShapeDtypeStruct((R, M), jnp.float32),
-        jax.ShapeDtypeStruct((R, M), jnp.float32),
-        jax.ShapeDtypeStruct((R, 1), jnp.float32),
-        jax.ShapeDtypeStruct((R, acc_cols), jnp.float32),
+        jax.ShapeDtypeStruct((M, Rp), jnp.float32),
+        jax.ShapeDtypeStruct((M, Rp), jnp.float32),
+        jax.ShapeDtypeStruct((M, Rp), jnp.float32),
+        jax.ShapeDtypeStruct((1, Rp), jnp.float32),
+        jax.ShapeDtypeStruct((acc_cols, Rp), jnp.float32),
     ]
-    if retries:
-        # the activation plane spans the WHOLE padded stream (event
-        # positions are global across k chunks), so its revisited output
-        # block is full-width and stays pinned in VMEM like the acc
-        out_specs.append(pl.BlockSpec((block_r, K), lambda r, k: (r, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((R, K), jnp.float32))
     if crashes:
         # the per-slot doom plane persists across k chunks like the pool
         out_specs.append(state_spec)
-        out_shape.append(jax.ShapeDtypeStruct((R, M), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((M, Rp), jnp.float32))
+    kw = {}
+    # VMEM of the streamed blocks: every [block_k, rb] sample block is
+    # double-buffered; a large explicit block_k raises the limit with it
+    vmem = 2 * sum(spec is samp_spec for spec in in_specs) * block_k * rb * 4
+    if retries:
+        # the activation plane spans the WHOLE padded stream (event
+        # positions are global across k chunks): one scratch per replica
+        # block, zeroed at its first chunk
+        vmem += K * rb * 4
+        kw["scratch_shapes"] = [pltpu.VMEM((K, rb), jnp.float32)]
+    if vmem > _SCOPED_VMEM // 2:
+        kw["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + _SCOPED_VMEM
+        )
     out = _pallas_call_32(
         kernel,
         inputs,
@@ -794,9 +830,16 @@ def faas_sweep_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        **kw,
     )
     alive_n, creation_n, busy_n, t_n, acc = out[:5]
-    return alive_n, creation_n, busy_n, t_n[:, 0], acc
+    return (
+        alive_n.T[:R],
+        creation_n.T[:R],
+        busy_n.T[:R],
+        t_n[0, :R],
+        acc.T[:R],
+    )
 
 
 def _pad_rows(x, pad_c, fill=None):
@@ -820,155 +863,59 @@ def _pad_rows(x, pad_c, fill=None):
 )
 def _pallas_sweep_rows(
     alive0, creation0, busy0, t0, t_exp, t_end, skip, dts, warms, colds,
-    *, block_k, window_bounds=None, grid_times=None,
-    t_timeout=None, p_fail=None, fail_u=None, is_first=None, child_pos=None,
-    crash_rate=None, crash_u=None, cap_edges=None, cap_values=None,
-    fused=None,
-    **kw,
+    *, block_k, fused=None, **kw,
 ):
     """The sweep engine's ``pallas`` row launcher (``BackendSpec.launch``):
-    pad rows to the replica block and arrivals to the chunk size, run
-    :func:`faas_sweep_pallas`, return the ``[C, cols]`` accumulator.
-
-    ``dts`` rows are gaps, or absolute times when ``kw['prestamped']`` —
-    both use the same 1e30 column fill: as a gap it jumps the clock past
-    the row's ``t_end``, as a timestamp it IS past ``t_end``, so padding
-    is inert either way.  Extra rows are copies of row 0, sliced off
-    after the launch.  Serves both the steady-state (scan) and transient
-    (temporal, via ``grid_times``) engines — the pool-state family.
+    run :func:`faas_sweep_pallas` on the ``[C, ...]`` rows at the chunk
+    :func:`sweep_layout` gives, and return the ``[C, cols]``
+    accumulator.  The kernel program pads and transposes the streams
+    itself (``dts`` rows are gaps, or absolute times when
+    ``kw['prestamped']`` — the 1e30 pad is inert as either).  Serves both
+    the steady-state (scan) and transient (temporal, via ``grid_times``)
+    engines — the pool-state family.
 
     With ``fused`` (a dict of ``dists``/``keys``/``params``/``fail_keys``/
     ``n_steps`` from the DrawPlan lowering, DESIGN.md §12) there are no
-    sample buffers at all: only the [C, 2] key/param pairs are padded, and
-    the return value is ``(acc[:C], t_final[:C])`` so the caller can check
-    stream coverage from the kernel's own clock.  Padded tail events past
+    sample buffers at all: only the [C, 2] key/param pairs go in, and the
+    return value is ``(acc, t_final)`` so the caller can check stream
+    coverage from the kernel's own clock.  Padded tail events past
     ``n_steps`` keep drawing from the counter but are inert once the clock
     clears ``t_end``.
     """
+    C = alive0.shape[0]
     if fused is not None:
-        C = alive0.shape[0]
         n = int(fused["n_steps"])
-        block_k = lane_chunk(block_k, n)
-        pad_c = (-C) % BLOCK_R
-        Kp = n + ((-n) % block_k)
-        row_pad = lambda x: _pad_rows(x, pad_c, fill=1.0)
-        keys = tuple(
-            _pad_rows(jnp.asarray(k, jnp.uint32), pad_c) for k in fused["keys"]
-        )
-        params = tuple(
-            _pad_rows(jnp.asarray(p, jnp.float32), pad_c) for p in fused["params"]
-        )
         rely_kw = {}
-        if t_timeout is not None:
+        if kw.get("t_timeout") is not None:
             rely_kw = dict(
-                t_timeout=row_pad(t_timeout),
-                p_fail=_pad_rows(p_fail, pad_c, fill=0.0),
-                fused_fail_keys=_pad_rows(
-                    jnp.asarray(fused["fail_keys"], jnp.uint32), pad_c
-                ),
+                reliability=True,
+                fused_fail_keys=jnp.asarray(fused["fail_keys"], jnp.uint32),
             )
         out = faas_sweep_pallas(
-            _pad_rows(alive0, pad_c),
-            _pad_rows(creation0, pad_c),
-            _pad_rows(busy0, pad_c),
-            _pad_rows(t0, pad_c, fill=0.0),
-            row_pad(t_exp),
-            None,
-            None,
-            None,
-            t_end=row_pad(t_end),
-            skip=row_pad(skip),
-            window_bounds=(
-                None if window_bounds is None else _pad_rows(window_bounds, pad_c)
-            ),
-            grid_times=(
-                None if grid_times is None else _pad_rows(grid_times, pad_c)
-            ),
-            block_r=BLOCK_R,
-            block_k=block_k,
+            alive0, creation0, busy0, t0, t_exp, None, None, None,
+            t_end=t_end,
+            skip=skip,
+            block_k=sweep_layout(C, n, block_k)["block_k"],
             interpret=_interpret(),
-            reliability=t_timeout is not None,
             fused_dists=tuple(fused["dists"]),
-            fused_k=Kp,
-            fused_keys=keys,
-            fused_params=params,
+            fused_k=n,
+            fused_keys=tuple(jnp.asarray(k, jnp.uint32) for k in fused["keys"]),
+            fused_params=tuple(jnp.asarray(p, jnp.float32) for p in fused["params"]),
             **rely_kw,
             **kw,
         )
-        return out[4][:C], out[3][:C]
-    C, n = dts.shape
-    block_k = lane_chunk(block_k, n)
-    pad_c = (-C) % BLOCK_R
-    pad_k = (-n) % block_k
-
-    def pad(x, col_fill):
-        if pad_k:
-            x = jnp.concatenate(
-                [x, jnp.full((x.shape[0], pad_k), col_fill, x.dtype)], axis=1
-            )
-        return _pad_rows(x, pad_c)
-
-    dts_p = pad(dts, 1e30)
-    warms_p, colds_p = pad(warms, 1.0), pad(colds, 1.0)
-    row_pad = lambda x: _pad_rows(x, pad_c, fill=1.0)
-    reliability = t_timeout is not None
-    retries = is_first is not None
-    rely_kw = {}
-    if reliability:
-        # padded events are inert (active=False via the 1e30 clock), so
-        # the sample fills only need to keep the arithmetic finite:
-        # fail_u=1.0 never fails (p_fail < 1), child=NO_CHILD never
-        # scatters, is_first=0 keeps padded events inactive
-        rely_kw = dict(
-            t_timeout=row_pad(t_timeout),
-            p_fail=_pad_rows(p_fail, pad_c, fill=0.0),
-            fail_u=pad(fail_u, 1.0),
-        )
-        if retries:
-            rely_kw.update(
-                is_first=pad(is_first, 0.0),
-                child_pos=pad(child_pos, NO_CHILD_F),
-            )
-    fault_kw = {}
-    if crash_u is not None:
-        # padded events sit past t_end (dt fill 1e30), so any doom they
-        # stamp is > t_end and never counted; the 0.0 fill just keeps the
-        # log finite
-        fault_kw.update(
-            crash_rate=row_pad(crash_rate), crash_u=pad(crash_u, 0.0)
-        )
-    if cap_values is not None:
-        fault_kw.update(
-            cap_edges=_pad_rows(jnp.asarray(cap_edges, jnp.float32), pad_c),
-            cap_values=_pad_rows(jnp.asarray(cap_values, jnp.float32), pad_c),
-        )
+        return out[4], out[3]
     out = faas_sweep_pallas(
-        _pad_rows(alive0, pad_c),
-        _pad_rows(creation0, pad_c),
-        _pad_rows(busy0, pad_c),
-        _pad_rows(t0, pad_c, fill=0.0),
-        row_pad(t_exp),
-        dts_p,
-        warms_p,
-        colds_p,
-        t_end=row_pad(t_end),
-        skip=row_pad(skip),
-        window_bounds=(
-            None if window_bounds is None else _pad_rows(window_bounds, pad_c)
-        ),
-        grid_times=(
-            None if grid_times is None else _pad_rows(grid_times, pad_c)
-        ),
-        block_r=BLOCK_R,
-        block_k=block_k,
+        alive0, creation0, busy0, t0, t_exp, dts, warms, colds,
+        t_end=t_end,
+        skip=skip,
+        block_k=sweep_layout(C, dts.shape[1], block_k)["block_k"],
         interpret=_interpret(),
-        reliability=reliability,
-        retries=retries,
-        **rely_kw,
-        **fault_kw,
+        reliability=kw.get("t_timeout") is not None,
+        retries=kw.get("is_first") is not None,
         **kw,
     )
-    return out[4][:C]
+    return out[4]
 
 
 def faas_block_step_pallas(
@@ -982,7 +929,7 @@ def faas_block_step_pallas(
     *,
     t_exp: float,
     max_concurrency: int,
-    block_r: int = 8,
+    block_r=None,
     interpret: bool = False,
 ):
     """Legacy single-chunk entry point (scalar threshold, no window masking).

@@ -10,7 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.execution import lane_chunk, register_backend
+from repro.core.execution import SUBLANE, register_backend
 from repro.core import drawplan as dp
 from repro.models.attention import (  # noqa: F401
     decode_attention_ref,
@@ -451,23 +451,20 @@ def _ref_sweep_rows(
     **kw,
 ):
     """The sweep engine's ``ref`` row launcher (``BackendSpec.launch``):
-    pads rows and arrivals exactly like the Pallas launcher so the twin
-    programs consume identically-shaped buffers — XLA may associate the
-    per-row slot reductions differently for different row counts, and a
-    shape mismatch between the twins shows up as rare 1-ulp drift in the
-    f32 integrals.  Serves both the steady-state (scan) and transient
-    (temporal, via ``grid_times``) engines.  With ``fused`` (DrawPlan
-    lowering dict, DESIGN.md §12) draws are regenerated inline from the
-    counter scheme and the return value is ``(acc, t_final)`` for the
-    coverage guard."""
-    from repro.kernels.faas_event_step import BLOCK_R, NO_CHILD_F, _pad_rows
+    steps as many events as the Pallas kernel does (its
+    :func:`~repro.kernels.faas_event_step.sweep_layout`), on rows padded
+    to whole sublane tiles.  Serves both the steady-state (scan) and
+    transient (temporal, via ``grid_times``) engines.  With ``fused``
+    (DrawPlan lowering dict, DESIGN.md §12) draws are regenerated inline
+    from the counter scheme and the return value is ``(acc, t_final)``
+    for the coverage guard."""
+    from repro.kernels.faas_event_step import NO_CHILD_F, _pad_rows, sweep_layout
 
+    C = alive0.shape[0]
+    pad_c = (-C) % SUBLANE
     if fused is not None:
-        C = alive0.shape[0]
         n = int(fused["n_steps"])
-        block_k = lane_chunk(block_k, n)
-        pad_c = (-C) % BLOCK_R
-        Kp = n + ((-n) % block_k)
+        Kp = sweep_layout(C, n, block_k)["stepped"]
         row_pad = lambda x: _pad_rows(x, pad_c, fill=1.0)
         rely_kw = {}
         if t_timeout is not None:
@@ -511,10 +508,8 @@ def _ref_sweep_rows(
             **kw,
         )
         return out[4][:C], out[3][:C]
-    C, n = dts.shape
-    block_k = lane_chunk(block_k, n)
-    pad_c = (-C) % BLOCK_R
-    pad_k = (-n) % block_k
+    n = dts.shape[1]
+    pad_k = sweep_layout(C, n, block_k)["stepped"] - n
 
     def pad(x, col_fill):
         if pad_k:

@@ -175,19 +175,32 @@ class TestRegistry:
 
     def test_block_k_auto_resolution(self):
         """block_k=None derives the chunk from the stream length and the
-        VMEM budget; explicit values are honoured (clamped to K); either
-        is rounded up to whole 128-lane tiles."""
-        from repro.core.execution import _AUTO_BLOCK_K_MAX
+        VMEM budget, in the fewest balanced chunks; explicit values are
+        honoured (clamped to K); either is rounded up to whole 8-event
+        sublane tiles.  The lane-major kernels (fleet, par) round to
+        whole 128-lane tiles instead."""
+        from repro.core.execution import (
+            _AUTO_BLOCK_K_MAX,
+            _AUTO_SUBLANE_BLOCK_K_MAX,
+        )
 
         e = Execution()
         assert e.block_k is None
-        # short stream: one chunk, rounded up to whole 128-lane tiles
-        assert e.resolved_block_k(800) == 896
-        assert e.resolved_block_k(10**6) == _AUTO_BLOCK_K_MAX
-        assert _AUTO_BLOCK_K_MAX % 128 == 0
+        # short stream: one chunk, rounded up to whole sublane tiles
+        assert e.resolved_block_k(300) == 304
+        # 800 events over the 680-event ceiling: two balanced chunks
+        assert _AUTO_SUBLANE_BLOCK_K_MAX == 680
+        assert e.resolved_block_k(800) == 400
+        assert e.resolved_block_k(10**6) <= _AUTO_SUBLANE_BLOCK_K_MAX
+        assert e.resolved_block_k(10**6) % 8 == 0
         assert Execution(block_k=256).resolved_block_k(800) == 256
-        assert Execution(block_k=100).resolved_block_k(800) == 128
-        assert Execution(block_k=4096).resolved_block_k(800) == 896
+        assert Execution(block_k=100).resolved_block_k(800) == 104
+        assert Execution(block_k=4096).resolved_block_k(800) == 800
+        # the lane-major kernels keep whole 128-lane chunks
+        assert e.resolved_lane_block_k(800) == 896
+        assert e.resolved_lane_block_k(10**6) == _AUTO_BLOCK_K_MAX
+        assert _AUTO_BLOCK_K_MAX % 128 == 0
+        assert Execution(block_k=100).resolved_lane_block_k(800) == 128
 
     def test_readme_capability_matrix_matches_registry(self):
         """The README capability matrix is generated from the registry;
@@ -208,7 +221,8 @@ class TestRegistry:
             base_scn(), over=OVER, key=jax.random.key(0), replicas=1,
             steps=STEPS, backend="ref",
         )
-        assert g.execution.block_k == -(-STEPS // 128) * 128
+        # STEPS=800 is two balanced chunks of whole sublane tiles
+        assert g.execution.block_k == 400
 
     def test_devices_without_shard_rejected(self):
         """devices= only takes effect through shard='grid'; a plan that

@@ -4,7 +4,8 @@ chip would refuse — unaligned lane slices, float iotas, 64-bit vector
 types, scoped-VMEM overruns — which interpret mode never sees.
 
 Shapes are real widths: 64 and 128 instance slots, the auto ``block_k``
-ceiling, two arrival chunks.  x64 stays on, as ``repro.core`` leaves it
+ceiling, two arrival chunks; the single-function kernel gets two
+lane-dense blocks of replica rows.  x64 stays on, as ``repro.core`` leaves it
 for every caller.  The topology is described inside a module fixture
 (never at import): only one process may load the TPU library at a time.
 """
@@ -16,12 +17,17 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.execution import _AUTO_BLOCK_K_MAX
+from repro.core.execution import _AUTO_BLOCK_K_MAX, Execution
 from repro.kernels import faas_event_step as fe
 
-R = 64  # 8 replica blocks
+R = 64  # 8 replica blocks of the lane-major kernels (fleet, par)
 BK = _AUTO_BLOCK_K_MAX
 K = 2 * BK
+# the single-function kernel: rows on lanes, two 128-row blocks, two
+# chunks at the auto block_k's ceiling
+R_FAAS = 2 * fe.row_block(10**6)
+BK_FAAS = Execution().resolved_block_k(10**6)
+K_FAAS = 2 * BK_FAAS
 W, G, E, Q, C_PAR = 24, 16, 4, 8, 4
 
 
@@ -75,8 +81,9 @@ FUSED_DISTS = {
 def _faas_variant(name, s, M):
     """Positional args and kwargs of one ``faas_sweep_pallas`` variant —
     each one a kernel a sweep launches."""
+    R, K = R_FAAS, K_FAAS
     row, samp, state = _spec(s, R), _spec(s, R, K), _spec(s, R, M)
-    kw = dict(t_end=row, skip=row, max_concurrency=M, block_k=BK)
+    kw = dict(t_end=row, skip=row, max_concurrency=M, block_k=BK_FAAS)
     if name in FUSED_DISTS:
         keys = _spec(s, R, 2, dtype=jnp.uint32)
         kw.update(
@@ -125,6 +132,15 @@ FAAS_VARIANTS = (
 def test_faas_sweep_compiles_for_v5e(one_chip, variant, slots):
     args, kw = _faas_variant(variant, one_chip, slots)
     _compile(fe.faas_sweep_pallas, args, dict(kw, interpret=False))
+
+
+def test_faas_sweep_compiles_at_an_explicit_lane_sized_chunk(one_chip):
+    """An explicit ``Execution(block_k=10880)`` is honoured: three
+    double-buffered [10880, 128] sample blocks pass the default scoped
+    VMEM, so the launch raises the kernel's limit with them."""
+    args, kw = _faas_variant("staged", one_chip, 64)
+    kw.update(block_k=BK, interpret=False)
+    _compile(fe.faas_sweep_pallas, args[:5] + (_spec(one_chip, R_FAAS, K),) * 3, kw)
 
 
 @pytest.mark.parametrize("slots", [64, 128])
